@@ -21,6 +21,8 @@ SUITES = ["fig6", "tables", "throughput", "insert", "roofline", "serving",
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     want = sys.argv[1:] or SUITES
     print(f"# benchmark run: suites={want}", flush=True)
     failures = []

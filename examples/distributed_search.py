@@ -15,6 +15,7 @@ os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 
 from repro.core import build_meta, build_store  # noqa: E402
 from repro.core.distributed import ShardedStore  # noqa: E402
@@ -29,7 +30,8 @@ def main():
     meta = build_meta(ds.data, 32, seed=0)
     store = build_store(ds.data, meta)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ss = ShardedStore(store, mesh)
     print(f"store: {store.spec.n_blocks} blocks sharded over "
           f"{ss.tp} memory instances ({ss.per_shard} blocks each)")
@@ -59,4 +61,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -284,4 +284,6 @@ def run_demo(args, ds, eng):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -32,18 +32,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.layout import Store
 
-try:                        # jax >= 0.5: top-level export, check_vma kwarg
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-except AttributeError:      # jax 0.4.x: experimental module, check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across the 0.4/0.5 API rename."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
-
 
 def _pad_blocks(arr: np.ndarray, mult: int) -> np.ndarray:
     pad = (-arr.shape[0]) % mult
@@ -97,16 +85,16 @@ class ShardedStore:
                           NamedSharding(self.mesh, P())),
             out_shardings=NamedSharding(self.mesh, P()))
         def fetch(graph_buf, vec_buf, block_ids):
-            gather = shard_map_compat(
+            gather = jax.shard_map(
                 local_gather,
                 mesh=self.mesh,
                 in_specs=(P(axis, None), P()),
-                out_specs=P())
+                out_specs=P(), check_vma=False)
             g = gather(graph_buf, block_ids)
-            v = shard_map_compat(
+            v = jax.shard_map(
                 local_gather, mesh=self.mesh,
                 in_specs=(P(axis, None), P()),
-                out_specs=P())(vec_buf, block_ids)
+                out_specs=P(), check_vma=False)(vec_buf, block_ids)
             return g, v
 
         return fetch
@@ -147,9 +135,9 @@ def abstract_fetch_lowered(store: Store, mesh: Mesh, m_blocks: int,
         return lax.psum(rows, axis)
 
     def fetch(graph_buf, vec_buf, block_ids):
-        f = lambda b, i: shard_map_compat(local_gather, mesh=mesh,
-                                          in_specs=(P(axis, None), P()),
-                                          out_specs=P())(b, i)
+        f = lambda b, i: jax.shard_map(local_gather, mesh=mesh,
+                                       in_specs=(P(axis, None), P()),
+                                       out_specs=P(), check_vma=False)(b, i)
         return f(graph_buf, block_ids), f(vec_buf, block_ids)
 
     n_ids = m_blocks * spec.fetch_blocks

@@ -224,6 +224,9 @@ class HNSW:
         )
 
 
+_DIST_ELEMS = 2**26   # distance-matrix elements per row chunk of the build
+
+
 def bulk_l0_graph(vectors: np.ndarray, m0: int, *, heuristic: bool = True,
                   slack: int = 2) -> np.ndarray:
     """Fast offline L0 graph build for one (small) partition.
@@ -244,51 +247,72 @@ def bulk_l0_graph(vectors: np.ndarray, m0: int, *, heuristic: bool = True,
     k = min(m0 * slack + 1, n)
     x2 = np.einsum("nd,nd->n", v, v)
     adj = np.full((n, m0), -1, np.int32)
-    chunk = max(1, int(2**26 / max(n, 1)))
+    chunk = max(1, int(_DIST_ELEMS / max(n, 1)))
     for s in range(0, n, chunk):
         d = x2[None, :] - 2.0 * v[s:s + chunk] @ v.T + x2[s:s + chunk, None]
-        for i in range(d.shape[0]):
-            d[i, s + i] = np.inf  # no self edge
+        rows = np.arange(d.shape[0])
+        d[rows, s + rows] = np.inf  # no self edge
         idx = np.argpartition(d, k - 1, axis=1)[:, :k]
         dd = np.take_along_axis(d, idx, axis=1)
         order = np.argsort(dd, axis=1)
         idx = np.take_along_axis(idx, order, axis=1)
         dd = np.take_along_axis(dd, order, axis=1)
-        for i in range(idx.shape[0]):
-            node = s + i
-            if not heuristic:
-                adj[node, :min(m0, k)] = idx[i, :m0]
-                continue
-            kept: list[int] = []
-            for dq, c in zip(dd[i], idx[i]):
-                if len(kept) >= m0:
-                    break
-                dc = dq
-                ok = True
-                for kk in kept:
-                    dk = float(np.sum(np.square(v[c] - v[kk])))
-                    if dk < dc:
-                        ok = False
-                        break
-                if ok:
-                    kept.append(int(c))
-            # backfill with nearest pruned (keepPruned)
-            for c in idx[i]:
-                if len(kept) >= m0:
-                    break
-                if int(c) not in kept:
-                    kept.append(int(c))
-            adj[node, :len(kept)] = kept
+        if not heuristic:
+            adj[s:s + len(idx), :min(m0, k)] = idx[:, :m0]
+            continue
+        sub = max(1, 2**22 // (k * v.shape[1]))   # ~16 MB of candidates
+        for t in range(0, len(idx), sub):
+            part = _heuristic_prune(v, x2, idx[t:t + sub], dd[t:t + sub], m0)
+            adj[s + t:s + t + len(part)] = part
     # reverse-edge augmentation: ensure in-degree (greedy reachability)
-    deg = (adj >= 0).sum(1)
+    nbrs = [[c for c in row if c >= 0] for row in adj.tolist()]
     for node in range(n):
-        for c in adj[node]:
-            if c < 0:
-                break
-            if deg[c] < m0 and node not in adj[c, :deg[c]]:
-                adj[c, deg[c]] = node
-                deg[c] += 1
+        for c in list(nbrs[node]):
+            lst = nbrs[c]
+            if len(lst) < m0 and node not in lst:
+                lst.append(node)
+    for node, lst in enumerate(nbrs):
+        adj[node, :len(lst)] = lst
     return adj
+
+
+def _heuristic_prune(v: np.ndarray, x2: np.ndarray, idx: np.ndarray,
+                     dd: np.ndarray, m0: int) -> np.ndarray:
+    """HNSW neighbor selection for a batch of nodes, vectorized over them.
+
+    ``idx``/``dd`` (C, k) are each node's candidates sorted by distance.
+    In candidate order, keep one if fewer than m0 are kept and it is no
+    closer to any kept neighbor than to the node; then backfill with the
+    nearest pruned ones (keepPruned).  Returns (C, m0) int32, -1 padded.
+
+    Candidate-pair distances come from one batched Gram matmul; only the
+    pairs whose comparison with the node distance falls inside the f32
+    error bound of that form are recomputed as the exact sum of squared
+    differences, so every keep/prune decision is the per-pair one.
+    """
+    c, k = idx.shape
+    cand = v[idx]                                         # (C, k, D)
+    cn = x2[idx]
+    norms = cn[:, :, None] + cn[:, None, :]
+    pair = norms - 2.0 * np.matmul(cand, cand.transpose(0, 2, 1))
+    bound = dd[:, :, None]                                # row j vs d(q, j)
+    closer = pair < bound
+    tol = (4 * v.shape[1] + 8) * np.finfo(np.float32).eps * norms
+    ci, cj, ck = np.nonzero(np.abs(pair - bound) <= tol)
+    exact = np.sum(np.square(v[idx[ci, cj]] - v[idx[ci, ck]]), axis=-1)
+    closer[ci, cj, ck] = exact < dd[ci, cj]
+    kept = np.zeros((c, k), bool)
+    count = np.zeros(c, np.int64)
+    for j in range(k):
+        ok = (count < m0) & ~(kept & closer[:, j, :]).any(axis=1)
+        kept[:, j] = ok
+        count += ok
+    # kept candidates first, then the pruned, each in distance order
+    rank = np.where(kept, 0, k) + np.arange(k)[None, :]
+    pick = np.argsort(rank, axis=1, kind="stable")[:, :m0]
+    out = np.full((c, m0), -1, np.int32)
+    out[:, :min(m0, k)] = np.take_along_axis(idx, pick, axis=1)
+    return out
 
 
 def brute_force_knn(data: np.ndarray, queries: np.ndarray,

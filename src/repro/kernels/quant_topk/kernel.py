@@ -7,7 +7,11 @@ f32), is dequantized in VMEM right before the MXU matmul, and the same
 running top-k scratch keeps HBM output at O(B*k).
 
 Per (q-tile, x-tile):
-  1. dequant: x = codes.f32 * scales broadcast over each group (VPU);
+  1. dequant: x = codes.f32 * scales broadcast over each group.  The
+     broadcast is one (BN, D//group) x (D//group, D) matmul against a 0/1
+     group-expansion matrix built from iotas: Mosaic refuses the
+     lane-splitting reshape ``(BN, D) -> (BN, D//group, group)``, and the
+     MXU product is exact at HIGHEST precision (one nonzero term per lane);
   2. dist tile (BQ, BN) via one MXU matmul + row/col norms;
   3. merge into the (BQ, k) running best (k unrolled argmin rounds).
 
@@ -42,10 +46,13 @@ def _kernel(n_valid_ref, q_ref, x_ref, s_ref, d_out_ref, i_out_ref,
     q = q_ref[...].astype(jnp.float32)                   # (BQ, D)
     codes = x_ref[...].astype(jnp.float32)               # (BN, D) int8 -> f32
     scales = s_ref[...]                                  # (BN, D // group)
-    bn, d = codes.shape
-    # dequantize: broadcast each group scale over its `group` lanes
-    x = (codes.reshape(bn, d // group, group)
-         * scales[:, :, None]).reshape(bn, d)
+    d = codes.shape[1]
+    # dequantize: expand[g, c] = 1 where lane c belongs to group g
+    lane = jax.lax.broadcasted_iota(jnp.int32, (d // group, d), 1)
+    grp = jax.lax.broadcasted_iota(jnp.int32, (d // group, d), 0) * group
+    expand = ((lane >= grp) & (lane < grp + group)).astype(jnp.float32)
+    x = codes * jnp.dot(scales, expand, precision=jax.lax.Precision.HIGHEST,
+                        preferred_element_type=jnp.float32)   # (BN, D)
 
     q2 = jnp.sum(q * q, axis=1, keepdims=True)           # (BQ, 1)
     x2 = jnp.sum(x * x, axis=1)[None, :]                 # (1, BN)

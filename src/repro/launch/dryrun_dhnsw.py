@@ -44,7 +44,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax import lax  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.core.distributed import shard_map_compat  # noqa: E402
 from repro.launch.dryrun import parse_collectives  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 
@@ -109,10 +108,10 @@ def make_step(mesh, variant: str):
         # replicated fetch: every chip receives every span (paper's
         # "cache in each compute instance" done naively on-pod)
         def step(vec_buf, block_ids, queries, pair_slot, pair_valid):
-            v = shard_map_compat(
+            v = jax.shard_map(
                 lambda b, i: local_gather(b, i, jnp.zeros((), b.dtype)),
                 mesh=mesh, in_specs=(P(axis, None), P()),
-                out_specs=P())(vec_buf, block_ids)
+                out_specs=P(), check_vma=False)(vec_buf, block_ids)
             rows = v.reshape(M_FETCH, -1)[pair_slot]
             return serve(rows, queries, pair_valid)
 
@@ -178,9 +177,9 @@ def make_step(mesh, variant: str):
             d, i = serve(rows, q[0], valid[0])
             return d[None], i[None]
 
-        return shard_map_compat(
+        return jax.shard_map(
             shard_body, mesh=mesh, in_specs=qspec,
-            out_specs=(bspec, bspec))(
+            out_specs=(bspec, bspec), check_vma=False)(
                 vec_buf, block_ids, queries, pair_slot, pair_valid)
 
     vec_dtype = (jnp.int8 if variant in ("int8_rest", "span_dma", "bf16_serve")

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.models.params import ParamDef
 
@@ -153,12 +152,12 @@ def _moe_shardmap(cfg, p, x, mesh):
 
     wspec_gu = P(ep_ax, None, fsdp_ax if shard_f else None)
     wspec_d = P(ep_ax, fsdp_ax if shard_f else None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P(batch_axes or None, None, None), P(None, None),
                   wspec_gu, wspec_gu, wspec_d),
         out_specs=(P(batch_axes or None, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["we_g"], p["we_u"], p["we_d"])
     return y, aux
 

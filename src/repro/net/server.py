@@ -416,7 +416,9 @@ def spawn_pool_servers(n: int = 1, *, host: str = "127.0.0.1", seed: int = 0,
     env = os.environ.copy()
     src = _src_path()
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # memory nodes never touch an accelerator: a chip belongs to one
+    # process, and that is the compute side that spawned them
+    env["JAX_PLATFORMS"] = "cpu"
     procs, endpoints, drains = [], [], []
     try:
         for i in range(n):

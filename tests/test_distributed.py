@@ -86,7 +86,6 @@ def test_compressed_allreduce_multidevice():
     out = _run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.core.distributed import shard_map_compat
         from repro.distributed.compression import (compressed_grad_reduce,
                                                    init_error_state)
         mesh = jax.make_mesh((8,), ("data",))
@@ -98,8 +97,9 @@ def test_compressed_allreduce_multidevice():
         def red(g, e):
             out, new = compressed_grad_reduce({"w": g[0]}, e, mesh)
             return out["w"], new
-        f = jax.jit(shard_map_compat(red, mesh=mesh,
-                    in_specs=(P("data"), P()), out_specs=P()))
+        f = jax.jit(jax.shard_map(red, mesh=mesh,
+                    in_specs=(P("data"), P()), out_specs=P(),
+                    check_vma=False))
         ghat, _ = f(grads["w"], err)
         # mean over replicas
         want = local.mean(0)

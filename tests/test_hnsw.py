@@ -80,3 +80,58 @@ def test_bulk_graph_greedy_search_recall(rng):
                                jnp.asarray(queries), 0, ef=48)
     rec = recall_at_k(np.asarray(i)[:, :5], gt)
     assert rec >= 0.85, rec
+
+
+def _per_node_prune_ref(v, m0):
+    """The HNSW neighbor heuristic one node and one pair at a time: the
+    reference the vectorized ``bulk_l0_graph`` must match exactly."""
+    n = v.shape[0]
+    k = min(2 * m0 + 1, n)
+    x2 = np.einsum("nd,nd->n", v, v)
+    d = x2[None, :] - 2.0 * v @ v.T + x2[:, None]
+    d[np.arange(n), np.arange(n)] = np.inf
+    idx = np.argpartition(d, k - 1, axis=1)[:, :k]
+    dd = np.take_along_axis(d, idx, axis=1)
+    order = np.argsort(dd, axis=1)
+    idx = np.take_along_axis(idx, order, axis=1)
+    dd = np.take_along_axis(dd, order, axis=1)
+    adj = np.full((n, m0), -1, np.int32)
+    for node in range(n):
+        kept = []
+        for dq, c in zip(dd[node], idx[node]):
+            if len(kept) >= m0:
+                break
+            if all(float(np.sum(np.square(v[c] - v[j]))) >= dq
+                   for j in kept):
+                kept.append(int(c))
+        for c in idx[node]:
+            if len(kept) >= m0:
+                break
+            if int(c) not in kept:
+                kept.append(int(c))
+        adj[node, :len(kept)] = kept
+    nbrs = [[c for c in row if c >= 0] for row in adj.tolist()]
+    for node in range(n):
+        for c in list(nbrs[node]):
+            if len(nbrs[c]) < m0 and node not in nbrs[c]:
+                nbrs[c].append(node)
+    for node, lst in enumerate(nbrs):
+        adj[node, :len(lst)] = lst
+    return adj
+
+
+@pytest.mark.parametrize("n,dim,dist_elems", [
+    (2, 8, 2**26), (5, 16, 2**26), (33, 16, 2**26), (600, 128, 2**26),
+    (200, 960, 2**26), (1100, 128, 1000 * 1100)])
+def test_bulk_l0_graph_matches_per_node_heuristic(rng, monkeypatch, n, dim,
+                                                  dist_elems):
+    """Clustered rows make near-ties common; every keep/prune decision
+    must still be the per-pair one (tiny n also covers the self edge, a
+    small distance budget the row chunks that large partitions take)."""
+    from repro.core import hnsw
+    monkeypatch.setattr(hnsw, "_DIST_ELEMS", dist_elems)
+    centers = rng.random((4, dim), dtype=np.float32)
+    v = (centers[rng.integers(0, 4, n)]
+         + 0.15 * rng.standard_normal((n, dim)).astype(np.float32))
+    np.testing.assert_array_equal(bulk_l0_graph(v, 16),
+                                  _per_node_prune_ref(v, 16))

@@ -118,7 +118,8 @@ def test_perf_flags_numerics_equivalence():
         from repro.train.train_step import make_train_step
         cfg = smoke_config("qwen3-8b").replace(vocab_size=512)
         shape = InputShape("t", 1024, 8, "train")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         step, in_sh, out_sh, _ = make_train_step(cfg, shape, mesh)
         params = init_params(M.param_defs(cfg), jax.random.key(0))
         opt = adamw.init(params)

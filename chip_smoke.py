@@ -154,14 +154,14 @@ def main(argv=None) -> int:
     want = "cpu" if args.rehearse else "tpu"
     if dev.platform != want:
         _fail(f"JAX found platform {dev.platform!r}, need {want!r}")
-    device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices())}
-    print(f"device: {json.dumps(device)}", flush=True)
-
+    # before the first line of output: without the repo, print nothing
     from repro.core import DHNSWEngine, EngineConfig, recall_at_k
     from repro.data.synthetic import sift_like
     from repro.launch.compile_cache import enable_compile_cache
 
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    print(f"device: {json.dumps(device)}", flush=True)
     print(f"compile_cache: {enable_compile_cache()}", flush=True)
     floors = RECALL_FLOORS.get(n)
     if floors is None:

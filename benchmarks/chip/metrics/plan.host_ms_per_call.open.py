@@ -1,0 +1,2 @@
+"""plan.host_ms_per_call.open: see readers.plan_host_ms_per_call."""
+from readers import plan_host_ms_per_call as read  # noqa: F401

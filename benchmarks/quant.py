@@ -31,7 +31,6 @@ import numpy as np
 from repro.core import DHNSWEngine, EngineConfig, recall_at_k
 from repro.core.cost_model import RDMA_100G
 from repro.data.synthetic import sift_like
-from repro.obs.trace import TRACER
 
 
 def run_cell(data, queries, gt, *, quant: str, exact_frac: float,
@@ -101,14 +100,7 @@ def kernel_ab(n: int = 4096, d: int = 128, k: int = 10,
 
 
 def run(*, smoke: bool = False, out: str = "BENCH_quant.json",
-        seed: int = 0, trace_out: str | None = None) -> dict:
-    # --trace records the kernel A/B through repro.obs: every
-    # quant_topk call becomes a ``kernel.quant_topk`` span tagged with
-    # impl=pallas|ref, so `python -m repro.obs.report` can put a number
-    # on the Pallas-vs-oracle gap per call (not just the 1-shot *_us)
-    if trace_out:
-        TRACER.configure()
-        TRACER.set_phase("kernel_ab")
+        seed: int = 0) -> dict:
     if smoke:
         n, n_rep, n_batches = 1500, 12, 2
         splits, pools = (0.25,), (0,)
@@ -117,8 +109,6 @@ def run(*, smoke: bool = False, out: str = "BENCH_quant.json",
         n, n_rep, n_batches = 20_000, 64, 4
         splits, pools = (0.0, 0.25, 0.5), (0, 20, 40)
         kab = kernel_ab(seed=seed)
-    if trace_out:
-        TRACER.set_phase(None)
     ds = sift_like(n=n, n_queries=256, seed=seed)
 
     rows = [run_cell(ds.data, ds.queries, ds.gt_ids, quant="none",
@@ -158,11 +148,6 @@ def run(*, smoke: bool = False, out: str = "BENCH_quant.json",
 
     print(f"kernel A/B: id_match {kab['id_match']:.3f}  "
           f"pallas {kab['pallas_us']} us vs ref {kab['ref_us']} us")
-    if trace_out:
-        n_spans = TRACER.save(trace_out)
-        TRACER.disable()
-        print(f"wrote {trace_out} ({n_spans} spans) — inspect with "
-              f"`python -m repro.obs.report {trace_out}`")
     blob = {"bench": "quant", "smoke": smoke, "n": n, "n_rep": n_rep,
             "n_batches": n_batches, "rows": rows, "kernel": kab}
     with open(out, "w") as f:
@@ -177,12 +162,8 @@ def main():
                     help="tiny CI config; crash-check only")
     ap.add_argument("--out", default="BENCH_quant.json")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--trace", default=None, metavar="FILE",
-                    help="record the kernel A/B (and the sweep) with "
-                         "repro.obs; write Chrome-trace JSON to FILE")
     args = ap.parse_args()
-    run(smoke=args.smoke, out=args.out, seed=args.seed,
-        trace_out=args.trace)
+    run(smoke=args.smoke, out=args.out, seed=args.seed)
 
 
 if __name__ == "__main__":

@@ -5,6 +5,12 @@ Everything here is static-shaped and jit-friendly.  A fetch span is
 unit one doorbell descriptor covers.  ``decode_span`` turns a span + its
 metadata row into padded search arrays; the two search paths (faithful
 graph walk / MXU scan) run on the decoded view.
+
+The jitted bodies name their steps with ``jax.named_scope`` as
+``<layer>/<step>`` (``serve/decode``, ``serve/walk``, ``serve/merge``,
+``fetch/write_slots``, ``rerank/gather_rows``, ``rerank/exact``): the
+names their operations carry in a profiler trace.  Scopes change only
+the operations' metadata, not the compiled program.
 """
 from __future__ import annotations
 
@@ -123,20 +129,24 @@ def serve_and_merge(spec: LayoutSpec, cache_g, cache_v, meta_table, queries,
     associative with the sequential stable merges the host loop did), so
     results are bit-identical to the old path.
     """
-    rows = meta_table[pair_pids]
-    qs = queries[pair_qi]          # padding qi == B clamps; masked below
+    with jax.named_scope("serve/decode"):
+        rows = meta_table[pair_pids]
+        qs = queries[pair_qi]      # padding qi == B clamps; masked below
 
     def one(slot, row, q, ok):
-        part = decode_span(spec, cache_g[slot], cache_v[slot], row)
-        if mode == "graph":
-            d, g = search_decoded_graph(part, q, k, ef)
-        else:
-            d, g = search_decoded_scan(part, q, k)
-        return jnp.where(ok, d, jnp.inf), jnp.where(ok, g, -1)
+        with jax.named_scope("serve/decode"):
+            part = decode_span(spec, cache_g[slot], cache_v[slot], row)
+        with jax.named_scope("serve/walk"):
+            if mode == "graph":
+                d, g = search_decoded_graph(part, q, k, ef)
+            else:
+                d, g = search_decoded_scan(part, q, k)
+            return jnp.where(ok, d, jnp.inf), jnp.where(ok, g, -1)
 
     d, g = jax.vmap(one)(pair_slots, rows, qs, pair_valid)
-    return merge_ranked(run_d, run_g, pair_qi, pair_ranks, d, g,
-                        n_lanes=n_lanes)
+    with jax.named_scope("serve/merge"):
+        return merge_ranked(run_d, run_g, pair_qi, pair_ranks, d, g,
+                            n_lanes=n_lanes)
 
 
 @functools.partial(jax.jit, static_argnames=("n_lanes",))
@@ -332,7 +342,8 @@ def gather_rows(vec_buf, rows, *, dim: int):
     rows from the serialized region.  ``rows`` (..., ) region row
     addresses into ``vec_buf.reshape(-1, dim)`` (-1 lanes gather row 0
     and are masked by the caller).  Returns (..., D) f32."""
-    return vec_buf.reshape(-1, dim)[jnp.maximum(rows, 0)]
+    with jax.named_scope("rerank/gather_rows"):
+        return vec_buf.reshape(-1, dim)[jnp.maximum(rows, 0)]
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -341,11 +352,12 @@ def rerank_gathered(vrows, queries, rows, gids, *, k: int):
     candidate rows (``gather_rows`` is the pool verb that produced
     ``vrows``).  rows (B, m) mark empty lanes with -1; gids (B, m).
     Returns the final (dists (B, k), gids (B, k))."""
-    d = jnp.sum(jnp.square(vrows - queries[:, None, :]), axis=-1)
-    d = jnp.where(rows >= 0, d, jnp.inf)
-    nd, ni = lax.top_k(-d, k)
-    g = jnp.take_along_axis(gids, ni, axis=1)
-    return -nd, jnp.where(jnp.isfinite(-nd), g, -1)
+    with jax.named_scope("rerank/exact"):
+        d = jnp.sum(jnp.square(vrows - queries[:, None, :]), axis=-1)
+        d = jnp.where(rows >= 0, d, jnp.inf)
+        nd, ni = lax.top_k(-d, k)
+        g = jnp.take_along_axis(gids, ni, axis=1)
+        return -nd, jnp.where(jnp.isfinite(-nd), g, -1)
 
 
 def rerank_exact(vec_buf, queries, rows, gids, *, dim: int, k: int):
@@ -403,9 +415,10 @@ def write_slots(spec: LayoutSpec, cache_g, cache_v, slot_ids, g_blocks,
 
     g_blocks: (n_fetch, fetch_blocks, gblk); slot_ids: (n_fetch,).
     """
-    cache_g = cache_g.at[slot_ids].set(g_blocks)
-    cache_v = cache_v.at[slot_ids].set(v_blocks)
-    return cache_g, cache_v
+    with jax.named_scope("fetch/write_slots"):
+        cache_g = cache_g.at[slot_ids].set(g_blocks)
+        cache_v = cache_v.at[slot_ids].set(v_blocks)
+        return cache_g, cache_v
 
 
 @functools.partial(jax.jit, static_argnames=("spec",))

@@ -135,9 +135,10 @@ def meta_route(meta_vectors, meta_adjacency, queries, entry, *, b: int,
     is the only index the compute pool holds; everything else is fetched.
     """
     ef = max(ef, 2 * b, 8)
-    d, i = batched_beam_search(meta_vectors, meta_adjacency, queries, entry,
-                               ef=ef, n_levels=n_levels)
-    return i[:, :b], d[:, :b]
+    with jax.named_scope("route/meta_walk"):
+        d, i = batched_beam_search(meta_vectors, meta_adjacency, queries,
+                                   entry, ef=ef, n_levels=n_levels)
+        return i[:, :b], d[:, :b]
 
 
 # ------------------------------------------------------------- scan mode

@@ -14,10 +14,12 @@ from repro.kernels.gather_blocks.ref import gather_blocks_ref
 def gather_blocks(buf, block_ids, *, interpret: bool | None = None,
                   use_ref: bool = False):
     """One doorbell batch: fetch ``block_ids`` rows of ``buf`` in a single
-    launch.  buf (n_blocks, blk); block_ids (m,) -> (m, blk)."""
-    block_ids = jnp.asarray(block_ids, jnp.int32)
-    if use_ref:
-        return gather_blocks_ref(buf, block_ids)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    return gather_blocks_pallas(buf, block_ids, interpret=interpret)
+    launch.  buf (n_blocks, blk); block_ids (m,) -> (m, blk).  Runs
+    under the ``fetch/gather_spans`` scope, its name in a profiler trace."""
+    with jax.named_scope("fetch/gather_spans"):
+        block_ids = jnp.asarray(block_ids, jnp.int32)
+        if use_ref:
+            return gather_blocks_ref(buf, block_ids)
+        if interpret is None:
+            interpret = jax.default_backend() == "cpu"
+        return gather_blocks_pallas(buf, block_ids, interpret=interpret)

@@ -3,14 +3,9 @@
 Pads inputs to block multiples, dispatches to the Pallas kernel
 (interpret=True on CPU — this container — compiled BlockSpecs on TPU),
 and restores inf/-1 padding semantics.  ``use_ref=True`` forces the
-pure-jnp oracle (benchmarks A/B against it).
-
-When the global tracer is enabled every call is wrapped in a
-``kernel.quant_topk`` span (attrs: impl=pallas|ref, B/N/D/k) that blocks
-on the result so the span duration is real device time, not dispatch
-time.  The traced block happens OUTSIDE the jitted function — a span
-recorder cannot live inside a traced/jitted body — and the numerical
-results are identical either way.
+pure-jnp oracle (benchmarks A/B against it).  The jitted body runs under
+the ``stage1/quant_topk`` scope, the name its operations carry in a
+profiler trace.
 """
 from __future__ import annotations
 
@@ -23,7 +18,6 @@ from repro.kernels.distance_topk.kernel import MASKED
 from repro.kernels.distance_topk.ops import _pad_to
 from repro.kernels.quant_topk.kernel import quant_topk_pallas
 from repro.kernels.quant_topk.ref import quant_topk_ref
-from repro.obs.trace import TRACER
 
 
 @functools.partial(jax.jit, static_argnames=("k", "group", "block_q",
@@ -32,23 +26,24 @@ from repro.obs.trace import TRACER
 def _quant_topk_jit(queries, codes, scales, k: int, group: int, n_valid, *,
                     block_q: int, block_n: int, interpret, use_ref: bool):
     """The jitted kernel body (see ``quant_topk`` for the contract)."""
-    if n_valid is None:
-        n_valid = codes.shape[0]
-    n_valid = jnp.asarray(n_valid, jnp.int32).reshape(())
-    if use_ref:
-        return quant_topk_ref(queries, codes, scales, k, group, n_valid)
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    B, D = queries.shape
-    qp = _pad_to(queries.astype(jnp.float32), block_q, 0)
-    cp = _pad_to(codes.astype(jnp.int8), block_n, 0)
-    sp = _pad_to(scales.astype(jnp.float32), block_n, 0)
-    d, i = quant_topk_pallas(qp, cp, sp, n_valid, k=k, group=group,
-                             block_q=block_q, block_n=block_n,
-                             interpret=interpret)
-    d, i = d[:B], i[:B]
-    bad = d >= MASKED * 0.99
-    return jnp.where(bad, jnp.inf, d), jnp.where(bad, -1, i)
+    with jax.named_scope("stage1/quant_topk"):
+        if n_valid is None:
+            n_valid = codes.shape[0]
+        n_valid = jnp.asarray(n_valid, jnp.int32).reshape(())
+        if use_ref:
+            return quant_topk_ref(queries, codes, scales, k, group, n_valid)
+        if interpret is None:
+            interpret = jax.default_backend() == "cpu"
+        B, D = queries.shape
+        qp = _pad_to(queries.astype(jnp.float32), block_q, 0)
+        cp = _pad_to(codes.astype(jnp.int8), block_n, 0)
+        sp = _pad_to(scales.astype(jnp.float32), block_n, 0)
+        d, i = quant_topk_pallas(qp, cp, sp, n_valid, k=k, group=group,
+                                 block_q=block_q, block_n=block_n,
+                                 interpret=interpret)
+        d, i = d[:B], i[:B]
+        bad = d >= MASKED * 0.99
+        return jnp.where(bad, jnp.inf, d), jnp.where(bad, -1, i)
 
 
 def auto_use_ref() -> bool:
@@ -71,15 +66,6 @@ def quant_topk(queries, codes, scales, k: int, group: int, n_valid=None, *,
     queries (B, D) f32, codes (N, D) int8, scales (N, D // group) f32
     -> (dists (B, k), ids (B, k)).  ``n_valid`` masks padded rows.
     """
-    if not TRACER.enabled:
-        return _quant_topk_jit(queries, codes, scales, k, group, n_valid,
-                               block_q=block_q, block_n=block_n,
-                               interpret=interpret, use_ref=use_ref)
-    with TRACER.span("kernel.quant_topk", tier="kernel",
-                     impl="ref" if use_ref else "pallas",
-                     B=int(queries.shape[0]), N=int(codes.shape[0]),
-                     D=int(codes.shape[1]), k=int(k)):
-        out = _quant_topk_jit(queries, codes, scales, k, group, n_valid,
-                              block_q=block_q, block_n=block_n,
-                              interpret=interpret, use_ref=use_ref)
-        return jax.block_until_ready(out)
+    return _quant_topk_jit(queries, codes, scales, k, group, n_valid,
+                           block_q=block_q, block_n=block_n,
+                           interpret=interpret, use_ref=use_ref)

@@ -5,7 +5,8 @@ text exposition format:
 
 * :func:`render_prometheus` — from a ``SearchServer.stats()`` snapshot
   (request counters, stage seconds, latency quantiles, queue depth,
-  cache hit ratio, failover counters, pool verb totals), optionally
+  cache hit ratio, failover counters, pool verb totals, programs
+  lowered), optionally
   joined by per-span duration histograms from the live tracer ring.
 * :func:`render_pool_server` — from a ``PoolServer`` ``stats()`` payload
   (the STATS verb): per-verb request counts, service seconds, payload
@@ -210,10 +211,15 @@ def render_prometheus(stats: Dict[str, Any],
                          stats.get(f"p{p}_ms", 0.0),
                          {"quantile": f"0.{p}"}))
     _head(out, "repro_serve_stage_seconds_total",
-          "cumulative per-stage seconds", "counter")
+          "cumulative per-stage seconds (fetch_model: the fabric cost "
+          "model's, not measured)", "counter")
     for stage, v in sorted(stats.get("breakdown_s", {}).items()):
         out.append(_line("repro_serve_stage_seconds_total", v,
                          {"stage": stage.removesuffix("_s")}))
+    if "compiles" in stats:
+        _head(out, "repro_compiles_total",
+              "programs this process lowered to XLA", "counter")
+        out.append(_line("repro_compiles_total", stats["compiles"]["n"]))
     _head(out, "repro_net_total", "NetLedger roll-up", "counter")
     for key, v in sorted(stats.get("net", {}).items()):
         out.append(_line("repro_net_total", v, {"what": key}))

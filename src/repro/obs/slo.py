@@ -74,7 +74,8 @@ class SLOTracker:
     :class:`SLO`) applies to tier ``"serve"`` (end-to-end request
     latency), or a ``{tier: spec}`` dict attaches an objective per tier
     (``"serve"`` / ``"fetch"`` / ``"queue"`` — whatever the caller
-    records).  ``record`` is a no-op for unconfigured tiers, so the
+    records; the serving tier's ``"fetch"`` is the fabric cost model's
+    fetch latency, not a measured one).  ``record`` is a no-op for unconfigured tiers, so the
     serve tier can feed every stage unconditionally.  ``key`` is the
     within-tier series — the serve tier passes the tenant.
     """

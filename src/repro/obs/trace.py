@@ -23,8 +23,17 @@ server-side spans) are attached with :meth:`Tracer.add` /
 :meth:`Tracer.add_span`.
 
 Tiers are free-form strings; the conventional taxonomy is documented in
-``docs/observability.md`` (serve / compute / pool / net / server / kernel
-/ bench).
+``docs/observability.md`` (serve / compute / pool / net / server / bench).
+
+Profiler clock
+--------------
+While the tracer is enabled, every ``with TRACER.span(...)`` block also
+enters a ``jax.profiler.TraceAnnotation`` of the span's name, so a JAX
+profiler trace taken meanwhile holds the span as a host event on the
+same clock as the device's operations.  Externally-timed spans
+(:meth:`Tracer.add`, :meth:`Tracer.add_span`) and events cannot be
+annotated after the fact and appear in the ring only.  JAX is imported
+when the tracer is first enabled, never when this module is imported.
 
 Tail-based sampling
 -------------------
@@ -83,7 +92,8 @@ _NULL = _NullSpan()
 class _Span:
     """Live span context manager; records itself into the tracer on exit."""
 
-    __slots__ = ("_tracer", "name", "tier", "attrs", "t0", "span_id", "parent_id")
+    __slots__ = ("_tracer", "name", "tier", "attrs", "t0", "span_id",
+                 "parent_id", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, tier: str, attrs: Dict[str, Any]):
         """Bind the span to *tracer*; nothing is recorded until ``__exit__``."""
@@ -94,13 +104,16 @@ class _Span:
         self.t0 = 0.0
         self.span_id = 0
         self.parent_id = 0
+        self._annotation = tracer._annotate(name)
 
     def __enter__(self) -> "_Span":
-        """Allocate an id, push onto the thread's parent stack, start the clock."""
+        """Allocate an id, push onto the thread's parent stack, open the
+        profiler annotation and start the clock."""
         tr = self._tracer
         self.parent_id = tr._current_id()
         self.span_id = next(tr._ids)
         tr._tls.span_id = self.span_id
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -110,8 +123,10 @@ class _Span:
         return self
 
     def __exit__(self, *exc: object) -> bool:
-        """Stop the clock, pop the parent stack, and record the span."""
+        """Stop the clock, close the annotation, pop the parent stack,
+        and record the span."""
         dur = time.perf_counter() - self.t0
+        self._annotation.__exit__(*exc)
         tr = self._tracer
         tr._tls.span_id = self.parent_id
         tr._record(self.name, self.tier, self.t0, dur, self.span_id, self.parent_id, self.attrs)
@@ -139,6 +154,7 @@ class Tracer:
         self._tids: Dict[int, int] = {}
         self._lock = threading.Lock()
         self._phase: Optional[str] = None
+        self._annotate: Any = None   # TraceAnnotation, once enabled
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -159,6 +175,9 @@ class Tracer:
         *tail_quantile* is the adaptive latency threshold over a rolling
         window of *tail_window* recent root latencies.
         """
+        if enabled and self._annotate is None:
+            from jax.profiler import TraceAnnotation
+            self._annotate = TraceAnnotation
         with self._lock:
             if capacity is not None:
                 self.capacity = int(capacity)
@@ -214,7 +233,8 @@ class Tracer:
     # -- recording ---------------------------------------------------------
 
     def span(self, name: str, tier: str = "-", **attrs: Any) -> Any:
-        """Open a timed span context; returns a shared no-op when disabled."""
+        """Open a timed span context, annotated on the profiler's clock;
+        returns a shared no-op when disabled."""
         if not self.enabled:
             return _NULL
         return _Span(self, name, tier, attrs)

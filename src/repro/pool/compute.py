@@ -36,7 +36,15 @@ from repro.pool.protocol import MemoryPool
 
 class ComputeClient:
     """Plans greedy search against a ``MemoryPool`` (build once, then
-    ``search``/``insert`` batches)."""
+    ``search``/``insert`` batches).
+
+    Each host step is a ``TRACER.span`` (``compute.route``,
+    ``compute.plan``, ``compute.fetch``, ``compute.serve``, ...).  The
+    ``compute.fetch`` and ``compute.serve`` spans, like the ``sub_s``
+    seconds around the serve rounds, time the host's dispatch of the
+    device work: JAX returns before the device is done.  The device's
+    own time is under the ``fetch/`` and ``serve/`` scopes of the jitted
+    bodies, in a profiler trace."""
 
     def __init__(self, cfg, pool_factory):
         self.cfg = cfg
@@ -184,30 +192,31 @@ class ComputeClient:
                  "n_rounds": 0, "n_pairs": 0}
 
         t0 = time.perf_counter()
-        pids = self._route(q_dev, b)
+        with TRACER.span("compute.route", tier="compute", B=B):
+            pids = self._route(q_dev, b)
         stats["meta_s"] = time.perf_counter() - t0
-        TRACER.add("compute.route", "compute", t0, stats["meta_s"], B=B)
 
         # plan (compute-instance CPU role)
         t0 = time.perf_counter()
-        owner_of = getattr(pool, "owner_of_pid", None)
-        if cfg.mode == "naive":
-            raw = SCH.naive_plan(pids)
-            # every pair is its own READ round trip (the 3.547 trips/
-            # query); dedup below is compute-only, so movement through
-            # the pool goes uncharged (ledger=None) — already posted
-            pool.post_span_reads(len(raw), ledger=ledger, doorbell=1,
-                                 pids=[p for _, p in raw])
-            uniq = sorted({p for _, p in raw})
-            cache = SCH.LRUCacheState(max(len(uniq), 1))
-            plan = SCH.plan_batch(pids, cache, doorbell=1)
-        else:
-            plan = SCH.plan_batch(pids, self.cache, doorbell=cfg.doorbell,
-                                  owner_of=owner_of)
+        with TRACER.span("compute.plan", tier="compute") as span:
+            owner_of = getattr(pool, "owner_of_pid", None)
+            if cfg.mode == "naive":
+                raw = SCH.naive_plan(pids)
+                # every pair is its own READ round trip (the 3.547 trips/
+                # query); dedup below is compute-only, so movement through
+                # the pool goes uncharged (ledger=None) — already posted
+                pool.post_span_reads(len(raw), ledger=ledger, doorbell=1,
+                                     pids=[p for _, p in raw])
+                uniq = sorted({p for _, p in raw})
+                cache = SCH.LRUCacheState(max(len(uniq), 1))
+                plan = SCH.plan_batch(pids, cache, doorbell=1)
+            else:
+                plan = SCH.plan_batch(pids, self.cache,
+                                      doorbell=cfg.doorbell,
+                                      owner_of=owner_of)
+            span.set(rounds=len(plan.rounds), fetches=plan.n_fetches,
+                     hits=plan.n_cache_hits)
         stats["plan_s"] = time.perf_counter() - t0
-        TRACER.add("compute.plan", "compute", t0, stats["plan_s"],
-                   rounds=len(plan.rounds), fetches=plan.n_fetches,
-                   hits=plan.n_cache_hits)
 
         # rounds: fetch -> serve -> merge (all device-side; the running
         # top-k is carried as (B, k) device arrays and each round folds
@@ -247,19 +256,20 @@ class ComputeClient:
                     continue
                 t0 = time.perf_counter()
                 n = len(rnd.serve_pairs)
-                npad = pow2_pad(n)
-                qi, ppid, pslot, prank, valid = rnd.serve_tensors(npad, B)
-                # n_lanes is fixed at b (a query never has more than b
-                # pairs in one round) so recompiles depend only on
-                # (B, npad)
-                run_d, run_g = DS.serve_and_merge(
-                    spec, cache_g, cache_v, mt_dev, q_dev, run_d, run_g,
-                    jnp.asarray(qi), jnp.asarray(ppid), jnp.asarray(pslot),
-                    jnp.asarray(prank), jnp.asarray(valid), k=k, ef=ef,
-                    mode=cfg.search_mode, n_lanes=b)
-                dt = time.perf_counter() - t0
-                stats["sub_s"] += dt
-                TRACER.add("compute.serve", "compute", t0, dt, pairs=n)
+                with TRACER.span("compute.serve", tier="compute", pairs=n):
+                    npad = pow2_pad(n)
+                    qi, ppid, pslot, prank, valid = rnd.serve_tensors(npad,
+                                                                      B)
+                    # n_lanes is fixed at b (a query never has more than
+                    # b pairs in one round) so recompiles depend only on
+                    # (B, npad)
+                    run_d, run_g = DS.serve_and_merge(
+                        spec, cache_g, cache_v, mt_dev, q_dev, run_d, run_g,
+                        jnp.asarray(qi), jnp.asarray(ppid),
+                        jnp.asarray(pslot), jnp.asarray(prank),
+                        jnp.asarray(valid), k=k, ef=ef,
+                        mode=cfg.search_mode, n_lanes=b)
+                stats["sub_s"] += time.perf_counter() - t0
                 stats["n_pairs"] += n
 
         t0 = time.perf_counter()
@@ -320,55 +330,54 @@ class ComputeClient:
         pool_p = jax.block_until_ready(pool_p)
         stats["sub_s"] += time.perf_counter() - t0
         t0 = time.perf_counter()
-        pool_h = np.asarray(pool_p)
-        live = pool_h[:, :, 1] >= 0
-        flat_rows = pool_h[:, :, 1][live]
-        flat_pids = pool_h[:, :, 2][live]
-        n_admitted = 0
-        if cfg.mode == "naive":
-            # every (query, row) need is its own remote read (real pids
-            # so a sharded pool can attribute each to its destination)
-            pool.post_row_reads([(int(p), 1) for p in flat_pids],
-                                ledger=ledger, doorbell=1)
-            stats["rerank_rows"] = int(len(flat_rows))
-            stats["rerank_hit_rows"] = 0
-        else:
-            # query-aware: each needed row moves at most once per batch
-            uniq_rows, first = np.unique(flat_rows, return_index=True)
-            uniq_pids = flat_pids[first]
-            resident = tiers.exact.resident()
-            hit = np.isin(uniq_pids, np.fromiter(resident, np.int64,
-                                                 len(resident)))
-            groups: dict[int, int] = {}
-            for p in uniq_pids[~hit].tolist():
-                groups[p] = groups.get(p, 0) + 1
-            items = sorted(groups.items())
-            pool.post_row_reads(
-                items, ledger=ledger,
-                doorbell=1 if cfg.mode == "no_doorbell" else cfg.doorbell)
-            if items:
-                ledger.save(pb * len(items)
-                            - sum(c for _, c in items) * row_b)
-            for p in set(uniq_pids[hit].tolist()):
-                tiers.exact.touch(int(p))
-            # cost-based admission: a partition whose cumulative missed
-            # re-rank rows already outweigh one span fetch is promoted
-            for p, cnt in items:
-                tiers.note_rerank_miss(int(p), cnt)
-                if tiers.should_admit(int(p), row_b, pb):
-                    slot, _ = tiers.admit_exact(int(p))
-                    g_b, v_b = pool.read_spans(np.array([int(p)]),
-                                               ledger=ledger, doorbell=1)
-                    self._cache_g, self._cache_v = DS.write_slots(
-                        spec, self._cache_g, self._cache_v,
-                        jnp.asarray([slot], jnp.int32), g_b, v_b)
-                    n_admitted += 1
-            stats["rerank_rows"] = int((~hit).sum())
-            stats["rerank_hit_rows"] = int(hit.sum())
-        dt = time.perf_counter() - t0
-        stats["plan_s"] += dt
-        TRACER.add("compute.rerank_plan", "compute", t0, dt,
-                   admitted=n_admitted)
+        with TRACER.span("compute.rerank_plan", tier="compute") as span:
+            pool_h = np.asarray(pool_p)
+            live = pool_h[:, :, 1] >= 0
+            flat_rows = pool_h[:, :, 1][live]
+            flat_pids = pool_h[:, :, 2][live]
+            n_admitted = 0
+            if cfg.mode == "naive":
+                # every (query, row) need is its own remote read (real pids
+                # so a sharded pool can attribute each to its destination)
+                pool.post_row_reads([(int(p), 1) for p in flat_pids],
+                                    ledger=ledger, doorbell=1)
+                stats["rerank_rows"] = int(len(flat_rows))
+                stats["rerank_hit_rows"] = 0
+            else:
+                # query-aware: each needed row moves at most once per batch
+                uniq_rows, first = np.unique(flat_rows, return_index=True)
+                uniq_pids = flat_pids[first]
+                resident = tiers.exact.resident()
+                hit = np.isin(uniq_pids, np.fromiter(resident, np.int64,
+                                                     len(resident)))
+                groups: dict[int, int] = {}
+                for p in uniq_pids[~hit].tolist():
+                    groups[p] = groups.get(p, 0) + 1
+                items = sorted(groups.items())
+                pool.post_row_reads(
+                    items, ledger=ledger,
+                    doorbell=1 if cfg.mode == "no_doorbell" else cfg.doorbell)
+                if items:
+                    ledger.save(pb * len(items)
+                                - sum(c for _, c in items) * row_b)
+                for p in set(uniq_pids[hit].tolist()):
+                    tiers.exact.touch(int(p))
+                # cost-based admission: a partition whose cumulative missed
+                # re-rank rows already outweigh one span fetch is promoted
+                for p, cnt in items:
+                    tiers.note_rerank_miss(int(p), cnt)
+                    if tiers.should_admit(int(p), row_b, pb):
+                        slot, _ = tiers.admit_exact(int(p))
+                        g_b, v_b = pool.read_spans(np.array([int(p)]),
+                                                   ledger=ledger, doorbell=1)
+                        self._cache_g, self._cache_v = DS.write_slots(
+                            spec, self._cache_g, self._cache_v,
+                            jnp.asarray([slot], jnp.int32), g_b, v_b)
+                        n_admitted += 1
+                stats["rerank_rows"] = int((~hit).sum())
+                stats["rerank_hit_rows"] = int(hit.sum())
+            span.set(admitted=n_admitted)
+        stats["plan_s"] += time.perf_counter() - t0
         stats["exact_admitted"] = n_admitted
 
         # stage-2 re-rank: exact distances over candidate rows only
@@ -399,32 +408,33 @@ class ComputeClient:
         include_graph = cfg.search_mode == "graph"
 
         t0 = time.perf_counter()
-        pids = self._route(q_dev, b)
+        with TRACER.span("compute.route", tier="compute", B=B):
+            pids = self._route(q_dev, b)
         stats["meta_s"] = time.perf_counter() - t0
-        TRACER.add("compute.route", "compute", t0, stats["meta_s"], B=B)
 
         # stage-1 plan against the quantized tier.  A quantized span
         # read moves the codes + codebook (and, in graph mode, the
         # adjacency blocks): 2 descriptors per span
         t0 = time.perf_counter()
-        if cfg.mode == "naive":
-            raw = SCH.naive_plan(pids)
-            pool.post_span_reads(len(raw), ledger=ledger, doorbell=1,
-                                 quant=True, quant_graph=include_graph,
-                                 pids=[p for _, p in raw])
-            ledger.save(len(raw) * (pb - qpb))
-            uniq = sorted({p for _, p in raw})
-            tiers = SCH.TieredCacheState(max(len(uniq), 1), 1)
-            plan = SCH.plan_batch(pids, tiers.quant, doorbell=1)
-        else:
-            tiers = self.tiers
-            plan = SCH.plan_batch(pids, tiers.quant, doorbell=cfg.doorbell,
-                                  owner_of=getattr(pool, "owner_of_pid",
-                                                   None))
+        with TRACER.span("compute.plan", tier="compute") as span:
+            if cfg.mode == "naive":
+                raw = SCH.naive_plan(pids)
+                pool.post_span_reads(len(raw), ledger=ledger, doorbell=1,
+                                     quant=True, quant_graph=include_graph,
+                                     pids=[p for _, p in raw])
+                ledger.save(len(raw) * (pb - qpb))
+                uniq = sorted({p for _, p in raw})
+                tiers = SCH.TieredCacheState(max(len(uniq), 1), 1)
+                plan = SCH.plan_batch(pids, tiers.quant, doorbell=1)
+            else:
+                tiers = self.tiers
+                plan = SCH.plan_batch(pids, tiers.quant,
+                                      doorbell=cfg.doorbell,
+                                      owner_of=getattr(pool, "owner_of_pid",
+                                                       None))
+            span.set(rounds=len(plan.rounds), fetches=plan.n_fetches,
+                     hits=plan.n_cache_hits)
         stats["plan_s"] = time.perf_counter() - t0
-        TRACER.add("compute.plan", "compute", t0, stats["plan_s"],
-                   rounds=len(plan.rounds), fetches=plan.n_fetches,
-                   hits=plan.n_cache_hits)
 
         # stage-1 rounds: fetch quantized spans -> pool candidates
         mt_dev = pool.read_meta()
@@ -469,18 +479,18 @@ class ComputeClient:
                     continue
                 t0 = time.perf_counter()
                 n = len(rnd.serve_pairs)
-                npad = pow2_pad(n)
-                qi, ppid, pslot, prank, valid = rnd.serve_tensors(npad, B)
-                pool_d, pool_p = DS.serve_quant_pool(
-                    spec, cache_qg, cache_qv, cache_qs, mt_dev, q_dev,
-                    pool_d, pool_p, jnp.asarray(qi), jnp.asarray(ppid),
-                    jnp.asarray(pslot), jnp.asarray(prank),
-                    jnp.asarray(valid), m=m, ef=max(ef, m),
-                    mode=cfg.search_mode, n_lanes=b)
-                dt = time.perf_counter() - t0
-                stats["sub_s"] += dt
-                TRACER.add("compute.serve", "compute", t0, dt, pairs=n,
-                           quant=True)
+                with TRACER.span("compute.serve", tier="compute", pairs=n,
+                                 quant=True):
+                    npad = pow2_pad(n)
+                    qi, ppid, pslot, prank, valid = rnd.serve_tensors(npad,
+                                                                      B)
+                    pool_d, pool_p = DS.serve_quant_pool(
+                        spec, cache_qg, cache_qv, cache_qs, mt_dev, q_dev,
+                        pool_d, pool_p, jnp.asarray(qi), jnp.asarray(ppid),
+                        jnp.asarray(pslot), jnp.asarray(prank),
+                        jnp.asarray(valid), m=m, ef=max(ef, m),
+                        mode=cfg.search_mode, n_lanes=b)
+                stats["sub_s"] += time.perf_counter() - t0
                 stats["n_pairs"] += n
         if cfg.mode != "naive":
             self._cache_qg, self._cache_qv, self._cache_qs = (
@@ -605,10 +615,8 @@ class ComputeClient:
         pool = self.pool
         spec = pool.spec
         vecs = np.asarray(vecs, np.float32).reshape(-1, spec.dim)
-        t0 = time.perf_counter()
-        pids = self._route(jnp.asarray(vecs), b=1)[:, 0]
-        TRACER.add("compute.route", "compute", t0,
-                   time.perf_counter() - t0, B=int(len(vecs)))
+        with TRACER.span("compute.route", tier="compute", B=int(len(vecs))):
+            pids = self._route(jnp.asarray(vecs), b=1)[:, 0]
         gids = np.arange(self._n0 + len(self._extra),
                          self._n0 + len(self._extra) + len(vecs))
         ledger = NetLedger(cfg.fabric)

@@ -199,10 +199,10 @@ class LocalPool(MemoryPool):
     # transport so ledger parity can never drift)
 
     def _gather_blocks(self, buf, ids):
-        if self.use_gather_kernel:
-            from repro.kernels.gather_blocks import ops as GO
-            return GO.gather_blocks(buf, ids)
-        return jnp.take(buf, ids, axis=0)
+        # the Pallas kernel, or its jnp oracle (one ``jnp.take``): both
+        # run under the wrapper's ``fetch/gather_spans`` trace scope
+        from repro.kernels.gather_blocks import ops as GO
+        return GO.gather_blocks(buf, ids, use_ref=not self.use_gather_kernel)
 
     def _staged_block_ids(self, block_ids: np.ndarray) -> np.ndarray:
         """Region block ids -> device rows (identity when fully staged)."""

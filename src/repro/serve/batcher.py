@@ -16,8 +16,9 @@ coalescing is therefore exactly the paper's batched query-aware loading
 with the "batch" assembled from independent requesters instead of one
 caller: partition dedup, doorbell grouping, and cache reuse all amortize
 across users.  Results are scattered back per request together with a
-queue/route/plan/fetch/serve latency breakdown, and the batcher keeps
-rolling p50/p95/p99 service metrics.
+queue/route/plan/serve latency breakdown and the fabric cost model's
+fetch latency (``fetch_model_s``, modeled, not measured), and the
+batcher keeps rolling p50/p95/p99 service metrics.
 
 Requests preserve arrival order: a window is drained as consecutive
 same-kind runs (search / insert), so a search submitted after an insert
@@ -97,8 +98,9 @@ class BatchPolicy:
     wfq_quantum: int = 8        # rows of credit per weight unit per sweep
     # latency SLOs (repro.obs.slo): a single spec ("p99<5ms" or an SLO)
     # watches end-to-end request latency per tenant; a {tier: spec} dict
-    # attaches objectives per stage ("serve" end-to-end, "fetch" pool
-    # wire time, "queue" wait).  None disables SLO tracking entirely.
+    # attaches objectives per stage ("serve" end-to-end, "fetch" the
+    # fabric cost model's pool wire time, "queue" wait).  None disables
+    # SLO tracking entirely.
     slo: Optional[object] = None
     slo_short_window: int = 64   # burn-rate fast window (requests)
     slo_long_window: int = 512   # burn-rate slow window (requests)
@@ -211,8 +213,10 @@ class ServeMetrics:
         self.n_fused_calls = 0
         self.n_rejected = 0
         self.fused_sizes = deque(maxlen=self.WINDOW)
+        # fetch_model_s is the fabric cost model's fetch latency
+        # (NetLedger.latency_s), not a measured time
         self.breakdown = {"queue_s": 0.0, "route_s": 0.0, "plan_s": 0.0,
-                          "fetch_s": 0.0, "serve_s": 0.0}
+                          "fetch_model_s": 0.0, "serve_s": 0.0}
         # NetLedger roll-up, recorded once per fused CALL (every request
         # in a window shares one engine call's network events)
         self.net = {"bytes_fetched": 0.0, "bytes_saved": 0.0,
@@ -289,7 +293,7 @@ class ServeMetrics:
             if self.slo is not None:
                 # feed every configured tier; record() ignores the rest
                 self.slo.record("serve", tenant, total_s)
-                for tier, key in (("fetch", "fetch_s"),
+                for tier, key in (("fetch", "fetch_model_s"),
                                   ("queue", "queue_s")):
                     if key in breakdown:
                         self.slo.record(tier, tenant, breakdown[key])
@@ -497,8 +501,10 @@ class MicroBatcher:
         pol = self.policy
         while True:
             with self._cv:
-                while not self._queue and not self._stop:
-                    self._cv.wait(timeout=0.1)
+                if not self._queue and not self._stop:
+                    with TRACER.span("serve.wait_request", tier="serve"):
+                        while not self._queue and not self._stop:
+                            self._cv.wait(timeout=0.1)
                 if self._stop:
                     return
                 # window: open at the oldest pending request; close on
@@ -508,12 +514,13 @@ class MicroBatcher:
                             + self.arrivals.wait_budget_s(
                                 pol,
                                 queue_empty=self._queue[0].empty_at_enqueue))
-                while (sum(r.vecs.shape[0] for r in self._queue)
-                       < pol.max_batch):
-                    left = deadline - time.perf_counter()
-                    if left <= 0 or self._stop:
-                        break
-                    self._cv.wait(timeout=left)
+                with TRACER.span("serve.wait_window", tier="serve"):
+                    while (sum(r.vecs.shape[0] for r in self._queue)
+                           < pol.max_batch):
+                        left = deadline - time.perf_counter()
+                        if left <= 0 or self._stop:
+                            break
+                        self._cv.wait(timeout=left)
                 window = self._take_window()
             self._dispatch_window(window)
 
@@ -667,13 +674,14 @@ class MicroBatcher:
                                              # (est nests the net dict)
                 stats["queue_s"] = t_disp - r.t_submit
                 stats["route_s"] = est["meta_s"]
-                stats["fetch_s"] = est["net"]["latency_s"]
+                stats["fetch_model_s"] = est["net"]["latency_s"]
                 stats["serve_s"] = est["sub_s"]
                 stats["fused_batch"] = B
                 stats["total_s"] = t_done - r.t_submit
                 self.metrics.record_request(stats["total_s"], {
                     "queue_s": stats["queue_s"], "route_s": est["meta_s"],
-                    "plan_s": est["plan_s"], "fetch_s": stats["fetch_s"],
+                    "plan_s": est["plan_s"],
+                    "fetch_model_s": stats["fetch_model_s"],
                     "serve_s": est["sub_s"]}, tenant=r.tenant)
                 r.future.set_result((d[off:off + m, :r.k],
                                      g[off:off + m, :r.k], stats))

@@ -16,6 +16,7 @@ from typing import Optional
 import numpy as np
 
 from repro.core.engine import DHNSWEngine
+from repro.obs.compiles import COMPILES
 from repro.serve.batcher import BatchPolicy, MicroBatcher
 
 
@@ -27,6 +28,7 @@ class SearchServer:
                  autostart: bool = True):
         self.engine = engine
         self.batcher = MicroBatcher(engine, policy, autostart=autostart)
+        COMPILES.install()
 
     # ------------------------------------------------------------ lifecycle
 
@@ -72,8 +74,11 @@ class SearchServer:
         queue depth, served rows + fair-queue ``share`` per tenant
         key), and under ``pool`` the latest memory-pool snapshot (verb
         totals; per-shard breakdown + migration counters when serving
-        through a ``ShardedPool``)."""
-        return self.batcher.metrics.snapshot()
+        through a ``ShardedPool``), and under ``compiles`` the programs
+        this process has lowered to XLA (``repro.obs.compiles``)."""
+        out = self.batcher.metrics.snapshot()
+        out["compiles"] = COMPILES.snapshot()
+        return out
 
     def metrics_text(self) -> str:
         """Prometheus text exposition of :meth:`stats` — SLO burn rates,

@@ -284,7 +284,7 @@ def test_server_stats_snapshot(engine, small_data):
         snap = srv.stats()
     assert snap["n_requests"] == 4
     assert snap["p50_ms"] > 0 and snap["p99_ms"] >= snap["p50_ms"]
-    for key in ("queue_s", "route_s", "plan_s", "fetch_s", "serve_s"):
+    for key in ("queue_s", "route_s", "plan_s", "fetch_model_s", "serve_s"):
         assert snap["breakdown_s"][key] >= 0
 
 

@@ -25,6 +25,7 @@ report CLI) and the serving benchmark's counted-pass determinism that
 from __future__ import annotations
 
 import json
+import re
 import sys
 import threading
 from pathlib import Path
@@ -158,6 +159,132 @@ def test_span_tree_through_search_server(pds):
         assert name in chain, (name, chain)
     queue = [s for s in spans if s["name"] == "serve.queue"]
     assert queue and all(s["tier"] == "serve" for s in queue)
+
+
+def test_spans_are_profiler_annotations(tmp_path):
+    """An enabled tracer's span is a host event of the same name in a
+    JAX profiler trace; a disabled tracer hands out the shared null
+    span, which neither annotates nor records."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from repro.obs.trace import _NULL
+    tr = Tracer()
+    assert tr.span("compute.plan") is _NULL
+    tr.configure(trace_id=21)
+    with jax.profiler.trace(str(tmp_path / "on")):
+        with tr.span("compute.plan", tier="compute", rounds=2):
+            with tr.span("compute.serve", tier="compute", pairs=3):
+                jax.block_until_ready(jax.numpy.ones(8) * 2)
+    tr.disable()
+    with jax.profiler.trace(str(tmp_path / "off")):
+        with tr.span("compute.route", tier="compute") as s:
+            assert s is _NULL
+    assert tr.snapshot() == []
+
+    def host_events(d):
+        path, = glob.glob(str(tmp_path / d / "**" / "*.xplane.pb"),
+                          recursive=True)
+        pd = ProfileData.from_file(path)
+        return {ev.name: ev for plane in pd.planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for ev in line.events}
+
+    on = host_events("on")
+    assert on["compute.plan"].start_ns <= on["compute.serve"].start_ns
+    assert on["compute.serve"].end_ns <= on["compute.plan"].end_ns
+    assert "compute.route" not in host_events("off")
+
+
+def test_compute_spans_keep_attrs_and_stats(pds):
+    """Route, plan and serve are spans around the host steps: they keep
+    their attributes, and the stats keep their seconds."""
+    data, queries = pds
+    eng = DHNSWEngine(EngineConfig(**CFG)).build(data)
+    TRACER.configure(trace_id=22)
+    _, _, stats = eng.search(queries[:4], k=5)
+    spans = TRACER.snapshot()
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s)
+    assert by["compute.route"][0]["attrs"]["B"] == 4
+    plan = by["compute.plan"][0]["attrs"]
+    assert plan["rounds"] == stats["n_rounds"]
+    assert plan["fetches"] == stats["n_fetches"]
+    assert plan["hits"] == stats["cache_hits"]
+    assert sum(s["attrs"]["pairs"] for s in by["compute.serve"]) \
+        == stats["n_pairs"]
+    assert stats["meta_s"] >= by["compute.route"][0]["dur"] > 0
+    assert stats["plan_s"] >= by["compute.plan"][0]["dur"] > 0
+    assert stats["sub_s"] >= sum(s["dur"] for s in by["compute.serve"]) > 0
+
+
+def test_batcher_waits_are_spans(pds):
+    """The dispatcher's wait for a first request and its wait while a
+    window stays open for more rows are spans of their own."""
+    data, queries = pds
+    eng = DHNSWEngine(EngineConfig(**CFG)).build(data)
+    TRACER.configure(trace_id=23)
+    with SearchServer(eng, BatchPolicy(max_batch=8, max_wait_s=0.2)) as srv:
+        srv.search(queries[:1], k=5)
+    waits = {s["name"]: s for s in TRACER.snapshot()
+             if s["name"].startswith("serve.wait_")}
+    assert set(waits) == {"serve.wait_request", "serve.wait_window"}
+    assert all(s["tier"] == "serve" for s in waits.values())
+    # one row of eight: the window waits out most of its 0.2 s budget
+    assert waits["serve.wait_window"]["dur"] > 0.1
+
+
+def test_serve_and_merge_scopes_name_its_steps(built_engine, monkeypatch):
+    """The serve round's compiled operations carry the serve/decode,
+    serve/walk and serve/merge scopes in their op_name."""
+    import jax
+
+    from repro.core import device_store as DS
+    seen = {}
+    real = DS.serve_and_merge
+
+    def spy(*args, **kw):
+        seen["args"] = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                        if hasattr(a, "dtype") else a for a in args]
+        seen["kw"] = kw
+        return real(*args, **kw)
+
+    monkeypatch.setattr(DS, "serve_and_merge", spy)
+    rng = np.random.default_rng(0)
+    built_engine.search(rng.standard_normal(
+        (2, built_engine.store.spec.dim)).astype(np.float32), k=5)
+    text = real.lower(*seen["args"], **seen["kw"]).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("serve/decode", "serve/walk", "serve/merge"):
+        assert any(scope in n for n in names), scope
+
+
+def test_compile_counter_counts_new_shapes(pds):
+    """``stats()["compiles"]`` counts each program lowered: one per new
+    shape of a jitted function, none for a shape seen before."""
+    import jax
+
+    from repro.obs.compiles import COMPILES
+    data, queries = pds
+    eng = DHNSWEngine(EngineConfig(**CFG)).build(data)
+    with SearchServer(eng, BatchPolicy(max_batch=8, max_wait_s=1e-3)) as srv:
+        f = jax.jit(lambda x: x * 3 + 1)
+        n0 = srv.stats()["compiles"]["n"]
+        f(np.ones(5, np.float32))
+        assert srv.stats()["compiles"]["n"] == n0 + 1
+        f(np.zeros(5, np.float32))
+        f(np.ones(7, np.float32))
+        assert srv.stats()["compiles"]["n"] == n0 + 2
+        for _ in range(2):      # the second search finds its spans cached
+            srv.search(queries[:1], k=5)
+        n1 = srv.stats()["compiles"]["n"]
+        srv.search(queries[:1], k=5)
+        assert srv.stats()["compiles"]["n"] == n1
+        snap = srv.stats()["compiles"]
+    assert snap == COMPILES.snapshot() and snap["seconds"] > 0
 
 
 # ------------------------------------------------------------ wire
@@ -333,6 +460,7 @@ def test_prometheus_renderers(pds):
     assert "repro_span_seconds_bucket" in txt
     assert 'repro_pool_verbs_total{verb="read_spans"}' in txt
     assert "repro_cache_hit_ratio" in txt
+    assert "repro_compiles_total" in txt
     # every exposition line parses: "name{...} value" with float value
     for line in txt.strip().splitlines():
         if line.startswith("#"):

@@ -86,10 +86,10 @@ def kernel_ab(n: int = 4096, d: int = 128, k: int = 10,
 
     out = {}
     for name, use_ref in (("pallas", False), ("ref", True)):
-        dd, ii = quant_topk(qj, cj, sj, k, 32, use_ref=use_ref)
+        dd, ii, _ = quant_topk(qj, cj, sj, k, 32, use_ref=use_ref)
         jax.block_until_ready((dd, ii))
         t0 = time.perf_counter()
-        dd, ii = quant_topk(qj, cj, sj, k, 32, use_ref=use_ref)
+        dd, ii, _ = quant_topk(qj, cj, sj, k, 32, use_ref=use_ref)
         jax.block_until_ready((dd, ii))
         out[f"{name}_us"] = round((time.perf_counter() - t0) * 1e6, 1)
         out[name] = (np.asarray(dd), np.asarray(ii))

@@ -109,9 +109,9 @@ def _check_stage1(client, queries, group: int) -> dict:
     m = max(int(client.cfg.rerank_m) or 2 * K, K)
     q = jnp.asarray(queries)
     args = (q, client._flat_codes, client._flat_scales, m, group)
-    d_k, i_k = jax.block_until_ready(
+    d_k, i_k, _ = jax.block_until_ready(
         quant_topk(*args, n_valid=client._flat_n))
-    d_r, i_r = jax.block_until_ready(
+    d_r, i_r, _ = jax.block_until_ready(
         quant_topk(*args, n_valid=client._flat_n, use_ref=True))
     d_k, i_k, d_r, i_r = map(np.asarray, (d_k, i_k, d_r, i_r))
     if (i_k < 0).any() or (i_k >= client._flat_n).any():

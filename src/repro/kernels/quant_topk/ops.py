@@ -2,10 +2,12 @@
 
 Pads inputs to block multiples, dispatches to the Pallas kernel
 (interpret=True on CPU — this container — compiled BlockSpecs on TPU),
-and restores inf/-1 padding semantics.  ``use_ref=True`` forces the
-pure-jnp oracle (benchmarks A/B against it).  The jitted body runs under
-the ``stage1/quant_topk`` scope, the name its operations carry in a
-profiler trace.
+and restores inf/-1 padding semantics.  The q-tile follows the batch
+(B rounded up to a sublane multiple of 8, capped at ``block_q``), so a
+served pow2 batch runs no padded query rows; the kernel masks the rows
+it still pads.  ``use_ref=True`` forces the pure-jnp oracle (benchmarks
+A/B against it).  The jitted body runs under the ``stage1/quant_topk``
+scope, the name its operations carry in a profiler trace.
 """
 from __future__ import annotations
 
@@ -31,19 +33,21 @@ def _quant_topk_jit(queries, codes, scales, k: int, group: int, n_valid, *,
             n_valid = codes.shape[0]
         n_valid = jnp.asarray(n_valid, jnp.int32).reshape(())
         if use_ref:
-            return quant_topk_ref(queries, codes, scales, k, group, n_valid)
+            d, i = quant_topk_ref(queries, codes, scales, k, group, n_valid)
+            return d, i, jnp.zeros(2, jnp.int32)
         if interpret is None:
             interpret = jax.default_backend() == "cpu"
         B, D = queries.shape
+        block_q = min(block_q, -(-B // 8) * 8)
         qp = _pad_to(queries.astype(jnp.float32), block_q, 0)
         cp = _pad_to(codes.astype(jnp.int8), block_n, 0)
         sp = _pad_to(scales.astype(jnp.float32), block_n, 0)
-        d, i = quant_topk_pallas(qp, cp, sp, n_valid, k=k, group=group,
-                                 block_q=block_q, block_n=block_n,
-                                 interpret=interpret)
+        d, i, work = quant_topk_pallas(qp, cp, sp, n_valid, jnp.int32(B),
+                                       k=k, group=group, block_q=block_q,
+                                       block_n=block_n, interpret=interpret)
         d, i = d[:B], i[:B]
         bad = d >= MASKED * 0.99
-        return jnp.where(bad, jnp.inf, d), jnp.where(bad, -1, i)
+        return jnp.where(bad, jnp.inf, d), jnp.where(bad, -1, i), work
 
 
 def auto_use_ref() -> bool:
@@ -58,13 +62,15 @@ def auto_use_ref() -> bool:
 
 
 def quant_topk(queries, codes, scales, k: int, group: int, n_valid=None, *,
-               block_q: int = 128, block_n: int = 256,
+               block_q: int = 128, block_n: int = 512,
                interpret: bool | None = None, use_ref: bool = False):
     """Top-k nearest database rows per query over an int8-quantized
     database (squared L2 on the dequantized values, ascending).
 
     queries (B, D) f32, codes (N, D) int8, scales (N, D // group) f32
-    -> (dists (B, k), ids (B, k)).  ``n_valid`` masks padded rows.
+    -> (dists (B, k), ids (B, k), work).  ``n_valid`` masks padded rows.
+    ``work`` is (2,) int32: the top-k merge rounds the kernel ran and
+    its grid steps (database tiles times q-tiles); zeros on the ref path.
     """
     return _quant_topk_jit(queries, codes, scales, k, group, n_valid,
                            block_q=block_q, block_n=block_n,

@@ -576,10 +576,13 @@ class ComputeClient:
         t0 = time.perf_counter()
         with TRACER.span("compute.stage1_flat", tier="compute",
                          rows=int(self._flat_n), B=B):
-            d, idx = quant_topk(q_dev, self._flat_codes, self._flat_scales,
-                                min(m, self._flat_n), cfg.quant_group,
-                                n_valid=self._flat_n, use_ref=use_ref)
+            d, idx, work = quant_topk(q_dev, self._flat_codes,
+                                      self._flat_scales, min(m, self._flat_n),
+                                      cfg.quant_group, n_valid=self._flat_n,
+                                      use_ref=use_ref)
+            work.copy_to_host_async()
             d, idx = jax.block_until_ready((d, idx))
+        rounds, tiles = np.asarray(work).tolist()
         safe = jnp.maximum(idx, 0)
         live = idx >= 0
         pool_p = jnp.stack([
@@ -600,6 +603,8 @@ class ComputeClient:
         stats["quant_kernel"] = "flat"
         stats["stage1_impl"] = "ref" if use_ref else "pallas"
         stats["flat_rows"] = int(self._flat_n)
+        stats["stage1_merge_rounds"] = rounds
+        stats["stage1_tiles"] = tiles
         return pool_d, pool_p, {
             "n_cache_hits": 0 if cold else B,
             "n_fetches": self.pool.spec.n_partitions if cold else 0}

@@ -46,6 +46,10 @@ from repro.core.engine import pow2_pad
 from repro.obs.slo import SLOTracker
 from repro.obs.trace import TRACER
 
+# per-call engine stats summed into ``stats()["engine"]``
+ENGINE_COUNTERS = ("cache_hits", "n_fetches", "n_rounds",
+                   "stage1_merge_rounds", "stage1_tiles")
+
 
 class AdmissionError(RuntimeError):
     """Token-bucket admission rejected a request (over offered-load cap)."""
@@ -229,9 +233,9 @@ class ServeMetrics:
         # when the engine serves through a ShardedPool)
         self.pool_snap: Optional[dict] = None
         # engine-side counters folded across fused calls (cache hit
-        # ratio, fetches, rounds) for the Prometheus exporter
-        self.engine_agg = {"cache_hits": 0.0, "n_fetches": 0.0,
-                           "n_rounds": 0.0}
+        # ratio, fetches, rounds, the int8 stage-1 kernel's merge rounds
+        # and tiles) for the Prometheus exporter
+        self.engine_agg = {key: 0.0 for key in ENGINE_COUNTERS}
         # per-tenant/per-tier SLO evaluation; attached by MicroBatcher
         # when BatchPolicy.slo is configured, else stays None
         self.slo: Optional[SLOTracker] = None
@@ -664,8 +668,7 @@ class MicroBatcher:
         t_done = time.perf_counter()
         self.metrics.record_call(
             B, n_queries=B, net=est["net"], pool=est.get("pool"),
-            engine={k2: est.get(k2, 0) for k2 in
-                    ("cache_hits", "n_fetches", "n_rounds")})
+            engine={k2: est.get(k2, 0) for k2 in ENGINE_COUNTERS})
         with TRACER.span("serve.merge", tier="serve", requests=len(group)):
             off = 0
             for r in group:

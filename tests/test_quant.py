@@ -270,6 +270,36 @@ def test_flat_kernel_insert_stays_coherent(qds):
     assert found == 1.0, (found, g, gids)
 
 
+def test_server_counts_stage1_merge_rounds(qds, monkeypatch):
+    """A served call through the Pallas stage 1 folds the kernel's merge
+    rounds and tiles into ``stats()["engine"]``; the jnp ref path counts
+    neither."""
+    from repro.kernels.quant_topk import ops
+    from repro.serve.batcher import BatchPolicy
+    from repro.serve.server import SearchServer
+
+    monkeypatch.setattr(ops, "auto_use_ref", lambda: False)
+    eng = DHNSWEngine(EngineConfig(mode="full", search_mode="scan",
+                                   n_rep=16, b=3, ef=32, cache_frac=0.6,
+                                   seed=3, quant="int8",
+                                   quant_kernel="auto")).build(
+        qds.data[:2000])
+    with SearchServer(eng, BatchPolicy(max_batch=8,
+                                       max_wait_s=0.05)) as srv:
+        futs = [srv.search_async(qds.queries[i], k=10) for i in range(8)]
+        stats = [f.result(timeout=300)[2] for f in futs]
+        snap = srv.stats()
+    assert stats[0]["stage1_impl"] == "pallas"
+    engine = snap["engine"]
+    tiles = 2048 // 512       # one q-tile; 2,000 rows padded to 2,048
+    assert engine["stage1_tiles"] == tiles * snap["n_fused_calls"] > 0
+    assert 0 < engine["stage1_merge_rounds"] <= 20 * engine["stage1_tiles"]
+    monkeypatch.setattr(ops, "auto_use_ref", lambda: True)
+    _, _, st = eng.search(qds.queries[:8], k=10)
+    assert st["stage1_impl"] == "ref"
+    assert st["stage1_merge_rounds"] == st["stage1_tiles"] == 0
+
+
 # ------------------------------------------------------------ serving
 
 def test_serve_routes_through_staged_path(qds, eng_int8):

@@ -17,6 +17,7 @@ from repro.core.layout import LayoutSpec
 from repro.kernels.distance_topk.kernel import distance_topk_pallas
 from repro.kernels.gather_blocks.kernel import gather_blocks_pallas
 from repro.kernels.quant_topk.kernel import quant_topk_pallas
+from repro.kernels.quant_topk.ops import _quant_topk_jit
 
 # the SIFT1M-shaped store: 128-d rows, L0 degree 16, 64 rows per block
 SIFT_SPEC = LayoutSpec(dim=128, deg=16, np_max=4096, ov_cap=512,
@@ -55,10 +56,22 @@ def _has_kernel(compiled) -> bool:
 @pytest.mark.parametrize("d,group", [(128, 32), (960, 32)])
 def test_quant_topk_compiles_for_v5e(one_chip, d, group):
     f = jax.jit(lambda q, c, s, nv: quant_topk_pallas(
-        q, c, s, nv, k=20, group=group))
+        q, c, s, nv, jnp.int32(256), k=20, group=group))
     compiled = f.lower(_shape(one_chip, (256, d), jnp.float32),
                        _shape(one_chip, (N_ROWS, d), jnp.int8),
                        _shape(one_chip, (N_ROWS, d // group), jnp.float32),
+                       _shape(one_chip, (), jnp.int32)).compile()
+    assert _has_kernel(compiled)
+
+
+def test_quant_topk_compiles_for_v5e_at_served_batch(one_chip):
+    """The served call: a pow2 batch of 64 runs a 64-row q-tile."""
+    f = jax.jit(lambda q, c, s, nv: _quant_topk_jit(
+        q, c, s, 20, 32, nv, block_q=128, block_n=256, interpret=False,
+        use_ref=False))
+    compiled = f.lower(_shape(one_chip, (64, 128), jnp.float32),
+                       _shape(one_chip, (N_ROWS, 128), jnp.int8),
+                       _shape(one_chip, (N_ROWS, 128 // 32), jnp.float32),
                        _shape(one_chip, (), jnp.int32)).compile()
     assert _has_kernel(compiled)
 

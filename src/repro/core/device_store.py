@@ -8,9 +8,10 @@ graph walk / MXU scan) run on the decoded view.
 
 The jitted bodies name their steps with ``jax.named_scope`` as
 ``<layer>/<step>`` (``serve/decode``, ``serve/walk``, ``serve/merge``,
-``fetch/write_slots``, ``rerank/gather_rows``, ``rerank/exact``): the
-names their operations carry in a profiler trace.  Scopes change only
-the operations' metadata, not the compiled program.
+``fetch/write_slots``, ``stage1/payload``, ``rerank/gather_rows``,
+``rerank/exact``): the names their operations carry in a profiler
+trace.  Scopes change only the operations' metadata, not the compiled
+program.
 """
 from __future__ import annotations
 
@@ -377,6 +378,25 @@ def gather_quant_rows(qvec_buf, qscale_buf, rows, *, dim: int, group: int):
     codes = qvec_buf.reshape(-1, dim)[safe]
     scales = qscale_buf.reshape(-1, dim // group)[safe]
     return codes, scales
+
+
+@functools.partial(jax.jit, static_argnames=("m",))
+def flat_candidates(flat_map, d, idx, *, m: int):
+    """Stage-1 payload of the flat scan: the kernel's (dists (B, k),
+    flat ids (B, k)) -> (pool_d (B, m), pool_p (B, m, 3)).  ``flat_map``
+    (N, 3) int32 holds each flat row's (gid, region row, pid); -1 ids
+    give (inf, -1 -1 -1) lanes, and k < m lanes are padded the same."""
+    with jax.named_scope("stage1/payload"):
+        live = idx >= 0
+        pool_p = jnp.where(live[..., None], flat_map[jnp.maximum(idx, 0)], -1)
+        pool_d = jnp.where(live, d, jnp.inf)
+        pad = m - d.shape[1]
+        if pad > 0:                    # flat DB smaller than the pool
+            pool_d = jnp.pad(pool_d, ((0, 0), (0, pad)),
+                             constant_values=jnp.inf)
+            pool_p = jnp.pad(pool_p, ((0, 0), (0, pad), (0, 0)),
+                             constant_values=-1)
+        return pool_d, pool_p
 
 
 @functools.partial(jax.jit, static_argnames=("spec",),

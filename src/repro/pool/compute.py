@@ -59,7 +59,7 @@ class ComputeClient:
         self._last_insert_net: Optional[dict] = None
         # dense-resident flat stage-1 state (quant_kernel route)
         self._flat_synced = False
-        self._flat_idx = None
+        self._flat_map = None   # (npad, 3) int32 device: gid, row, pid
 
     @property
     def store(self):
@@ -531,15 +531,11 @@ class ComputeClient:
         rows, gids, pids = LA.flat_quant_rows(self.pool.store)
         n = len(rows)
         npad = pow2_pad(max(n, 1), lo=256)
-        self._flat_idx = np.full(npad, -1, np.int64)
-        self._flat_idx[:n] = rows
-        self._flat_gid = np.full(npad, -1, np.int64)
-        self._flat_gid[:n] = gids
-        self._flat_pid = np.full(npad, -1, np.int64)
-        self._flat_pid[:n] = pids
+        flat_map = np.full((npad, 3), -1, np.int32)
+        flat_map[:n] = np.stack([gids, rows, pids], axis=1)
+        self._flat_map = jnp.asarray(flat_map)
         self._flat_n = n
-        codes, scales = self.pool.read_quant_rows(
-            jnp.asarray(self._flat_idx, jnp.int32))
+        codes, scales = self.pool.read_quant_rows(self._flat_map[:, 1])
         self._flat_codes = jax.block_until_ready(codes)
         self._flat_scales = scales
         # mark every partition resident so insert invalidation (drop)
@@ -572,6 +568,7 @@ class ComputeClient:
                            - self.pool.spec.quant_partition_bytes(
                                include_graph=False)))
         stats["plan_s"] = time.perf_counter() - t0
+        stats["stage1_map_uploads"] = int(cold)
 
         t0 = time.perf_counter()
         with TRACER.span("compute.stage1_flat", tier="compute",
@@ -583,20 +580,7 @@ class ComputeClient:
             work.copy_to_host_async()
             d, idx = jax.block_until_ready((d, idx))
         rounds, tiles = np.asarray(work).tolist()
-        safe = jnp.maximum(idx, 0)
-        live = idx >= 0
-        pool_p = jnp.stack([
-            jnp.where(live, jnp.asarray(self._flat_gid)[safe], -1),
-            jnp.where(live, jnp.asarray(self._flat_idx)[safe], -1),
-            jnp.where(live, jnp.asarray(self._flat_pid)[safe], -1),
-        ], axis=-1).astype(jnp.int32)
-        pool_d = jnp.where(live, d, jnp.inf)
-        if pool_d.shape[1] < m:           # flat DB smaller than the pool
-            pad = m - pool_d.shape[1]
-            pool_d = jnp.pad(pool_d, ((0, 0), (0, pad)),
-                             constant_values=jnp.inf)
-            pool_p = jnp.pad(pool_p, ((0, 0), (0, pad), (0, 0)),
-                             constant_values=-1)
+        pool_d, pool_p = DS.flat_candidates(self._flat_map, d, idx, m=m)
         stats["sub_s"] += time.perf_counter() - t0
         stats["n_rounds"] = 1
         stats["n_pairs"] = B
@@ -658,7 +642,7 @@ class ComputeClient:
         the writer already holds the row (it produced the WRITE), so
         this is pure compute-side bookkeeping — no wire traffic."""
         n = self._flat_n
-        if n >= len(self._flat_idx):
+        if n >= self._flat_map.shape[0]:
             self._flat_synced = False        # outgrew the pad: resync
             return
         mrow = self.pool.store.meta_table[pid]
@@ -669,12 +653,11 @@ class ComputeClient:
         co = LA.overflow_write_coords(self.pool.spec, group, slot)
         row = (co["vec_block"] * self.pool.spec.slot_vecs
                + co["vec_off"] // self.pool.spec.dim)
-        self._flat_idx[n] = row
-        self._flat_gid[n] = gid
-        self._flat_pid[n] = pid
         self._flat_n = n + 1
         # only row n changed: single-row gather + in-place scatter, so a
         # flat-route insert stays O(D), not O(N*D)
+        self._flat_map = self._flat_map.at[n].set(
+            jnp.asarray([gid, row, pid], jnp.int32))
         codes, scales = self.pool.read_quant_rows(
             jnp.asarray([row], jnp.int32))
         self._flat_codes = self._flat_codes.at[n].set(codes[0])

@@ -48,7 +48,8 @@ from repro.obs.trace import TRACER
 
 # per-call engine stats summed into ``stats()["engine"]``
 ENGINE_COUNTERS = ("cache_hits", "n_fetches", "n_rounds",
-                   "stage1_merge_rounds", "stage1_tiles")
+                   "stage1_merge_rounds", "stage1_tiles",
+                   "stage1_map_uploads")
 
 
 class AdmissionError(RuntimeError):

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import DHNSWEngine, EngineConfig, recall_at_k
-from repro.core.cost_model import RDMA_100G
+from repro.core.cost_model import RDMA_100G, NetLedger
 from repro.quant.codec import dequantize_groups, quantize_groups
 
 CFG = dict(mode="full", search_mode="scan", n_rep=32, b=6, ef=48,
@@ -253,21 +253,148 @@ def test_auto_kernel_picks_ref_impl_on_cpu(qds):
     assert st_ref["stage1_impl"] == "ref"
 
 
+FLAT = dict(mode="full", search_mode="scan", n_rep=16, b=3, ef=32,
+            cache_frac=0.6, seed=3, quant="int8", quant_kernel="auto")
+
+
+def _expected_flat_map(store, npad: int) -> np.ndarray:
+    """The flat view's (gid, region row, pid) map as the store lays its
+    rows out, then -1 padding to ``npad``."""
+    from repro.core import layout as LA
+    rows, gids, pids = LA.flat_quant_rows(store)
+    out = np.full((npad, 3), -1, np.int64)
+    out[:len(rows)] = np.stack([gids, rows, pids], axis=1)
+    return out
+
+
+def _parent_payload(host_map, d, idx, m: int):
+    """The eager payload construction the flat stage 1 ran per call
+    before its id map moved to the device (the oracle): three int64
+    host columns, put on the device and gathered on every call."""
+    import jax.numpy as jnp
+    flat_gid, flat_idx, flat_pid = (host_map[:, c].astype(np.int64)
+                                    for c in range(3))
+    safe = jnp.maximum(idx, 0)
+    live = idx >= 0
+    pool_p = jnp.stack([
+        jnp.where(live, jnp.asarray(flat_gid)[safe], -1),
+        jnp.where(live, jnp.asarray(flat_idx)[safe], -1),
+        jnp.where(live, jnp.asarray(flat_pid)[safe], -1),
+    ], axis=-1).astype(jnp.int32)
+    pool_d = jnp.where(live, d, jnp.inf)
+    if pool_d.shape[1] < m:
+        pad = m - pool_d.shape[1]
+        pool_d = jnp.pad(pool_d, ((0, 0), (0, pad)),
+                         constant_values=jnp.inf)
+        pool_p = jnp.pad(pool_p, ((0, 0), (0, pad), (0, 0)),
+                         constant_values=-1)
+    return pool_d, pool_p
+
+
+@pytest.fixture(scope="module")
+def eng_flat(qds):
+    eng = DHNSWEngine(EngineConfig(**FLAT)).build(qds.data[:2000])
+    eng.search(qds.queries[:8], k=10)         # cold sync
+    return eng
+
+
+@pytest.mark.parametrize("m,dead", [(20, False), (20, True),
+                                    (3000, False), (3000, True)],
+                         ids=["m20", "m20-dead", "m-over-rows",
+                              "m-over-rows-dead"])
+def test_flat_stage1_payload_matches_eager_oracle(qds, eng_flat, monkeypatch,
+                                                  m, dead):
+    """``_stage1_flat``'s jitted payload over the device-resident id map
+    is bitwise the eager per-call construction from host columns, with
+    the flat DB larger or smaller than ``m`` (2,000 rows, padded to m)
+    and with dead ``-1`` lanes in the kernel's output."""
+    import jax.numpy as jnp
+
+    from repro.kernels.quant_topk import ops
+    client = eng_flat.client
+    seen = {}
+    topk = ops.quant_topk
+
+    def spy(*args, **kw):
+        d, idx, work = topk(*args, **kw)
+        if dead:        # every third lane of every row comes back empty
+            kill = (jnp.arange(idx.shape[1]) % 3 == 1)[None, :]
+            d, idx = jnp.where(kill, jnp.inf, d), jnp.where(kill, -1, idx)
+        seen["d"], seen["idx"] = d, idx
+        return d, idx, work
+    monkeypatch.setattr(ops, "quant_topk", spy)
+    q = jnp.asarray(qds.queries[:8])
+    stats = {"sub_s": 0.0}
+    pool_d, pool_p, _ = client._stage1_flat(q, 8, m, NetLedger(RDMA_100G),
+                                            stats)
+    assert stats["stage1_map_uploads"] == 0
+    host_map = np.asarray(client._flat_map)
+    want_d, want_p = _parent_payload(host_map, seen["d"], seen["idx"], m)
+    assert pool_d.shape == (8, m) and pool_p.shape == (8, m, 3)
+    assert pool_d.dtype == want_d.dtype and pool_p.dtype == want_p.dtype
+    assert np.array_equal(np.asarray(pool_d), np.asarray(want_d))
+    assert np.array_equal(np.asarray(pool_p), np.asarray(want_p))
+    lanes = np.asarray(seen["idx"]).shape[1]
+    assert lanes == min(m, client._flat_n)
+    assert (np.asarray(pool_p)[:, lanes:] == -1).all()
+    if dead:
+        assert (np.asarray(pool_p)[:, 1:lanes:3] == -1).all()
+        assert np.isinf(np.asarray(pool_d)[:, 1:lanes:3]).all()
+
+
 def test_flat_kernel_insert_stays_coherent(qds):
     """Appends keep the dense-resident flat view coherent without a
-    resync: the inserted vector is immediately a stage-1 candidate."""
-    eng = DHNSWEngine(EngineConfig(mode="full", search_mode="scan",
-                                   n_rep=16, b=3, ef=32, cache_frac=0.6,
-                                   seed=3, quant="int8",
-                                   quant_kernel="auto")).build(
-        qds.data[:2000])
-    eng.search(qds.queries[:8], k=10)         # cold sync
+    resync: the inserted vector is immediately a stage-1 candidate, the
+    device id map gains exactly the appended rows, and no warm search
+    rebuilds the map."""
+    eng = DHNSWEngine(EngineConfig(**FLAT)).build(qds.data[:2000])
+    client = eng.client
+    _, _, st = eng.search(qds.queries[:8], k=10)         # cold sync
+    assert st["stage1_map_uploads"] == 1
+    npad = client._flat_map.shape[0]
+    synced = _expected_flat_map(eng.store, npad)
+    assert np.array_equal(np.asarray(client._flat_map), synced)
+    _, _, st = eng.search(qds.queries[:8], k=10)
+    assert st["stage1_map_uploads"] == 0
     new = qds.queries[:4] + 0.001
     gids = eng.insert(new)
+    n0 = int((synced[:, 0] >= 0).sum())
+    got = np.asarray(client._flat_map)
+    assert np.array_equal(got[:n0], synced[:n0])
+    assert client._flat_n == n0 + len(gids)
+    assert got[n0:n0 + len(gids), 0].tolist() == gids.tolist()
+    assert (got[n0 + len(gids):] == -1).all()
+    # the appended rows are the store's own new rows, none other
+    live_now = {tuple(r) for r in _expected_flat_map(eng.store, npad)
+                if r[0] >= 0}
+    assert {tuple(r) for r in got[:client._flat_n]} == live_now
     d, g, st = eng.search(new, k=3)
     assert st.get("quant_kernel") == "flat"
+    assert st["stage1_map_uploads"] == 0
     found = np.mean([gid in g[i] for i, gid in enumerate(gids)])
     assert found == 1.0, (found, g, gids)
+
+
+def test_flat_kernel_resyncs_map_after_repack(qds):
+    """An insert that overflows its group forces a repack; the next
+    search rebuilds the id map from the repacked store (one upload),
+    and the warm searches after it upload nothing."""
+    eng = DHNSWEngine(EngineConfig(**FLAT)).build(qds.data[:2000])
+    client = eng.client
+    eng.search(qds.queries[:8], k=10)                    # cold sync
+    ov = eng.store.spec.ov_cap
+    new = qds.data[42][None, :] + 0.0005 * np.random.default_rng(
+        0).standard_normal((ov + 3, eng.store.spec.dim)).astype(np.float32)
+    gids = eng.insert(new)
+    assert not client._flat_synced                       # repack unsynced it
+    _, g, st = eng.search(new[:8], k=3)
+    assert st["stage1_map_uploads"] == 1
+    npad = client._flat_map.shape[0]
+    assert np.array_equal(np.asarray(client._flat_map),
+                          _expected_flat_map(eng.store, npad))
+    assert set(gids.tolist()) <= set(np.asarray(client._flat_map)[:, 0])
+    _, _, st = eng.search(new[:8], k=3)
+    assert st["stage1_map_uploads"] == 0
 
 
 def test_server_counts_stage1_merge_rounds(qds, monkeypatch):
@@ -294,6 +421,7 @@ def test_server_counts_stage1_merge_rounds(qds, monkeypatch):
     tiles = 2048 // 512       # one q-tile; 2,000 rows padded to 2,048
     assert engine["stage1_tiles"] == tiles * snap["n_fused_calls"] > 0
     assert 0 < engine["stage1_merge_rounds"] <= 20 * engine["stage1_tiles"]
+    assert engine["stage1_map_uploads"] == 1      # the first call's sync
     monkeypatch.setattr(ops, "auto_use_ref", lambda: True)
     _, _, st = eng.search(qds.queries[:8], k=10)
     assert st["stage1_impl"] == "ref"

@@ -2,9 +2,11 @@
 
 Everything here is static-shaped and jit-friendly.  A fetch span is
 ``(fetch_blocks, gblk)`` int32 + ``(fetch_blocks, vblk)`` float32 — the
-unit one doorbell descriptor covers.  ``decode_span`` turns a span + its
-metadata row into padded search arrays; the two search paths (faithful
-graph walk / MXU scan) run on the decoded view.
+unit one doorbell descriptor covers — or, in the int8 tier, the same
+graph blocks + int8 codes + their codebook scales.  ``decode_span`` turns
+a span of either tier + its metadata row into padded search arrays; the
+two search paths (faithful graph walk / MXU scan) run on the decoded
+view, and ``serve_and_merge`` serves one round of either tier.
 
 The jitted bodies name their steps with ``jax.named_scope`` as
 ``<layer>/<step>`` (``serve/decode``, ``serve/walk``, ``serve/merge``,
@@ -35,28 +37,37 @@ class DecodedPartition(NamedTuple):
     entry: jax.Array      # () local entry id (the representative)
 
 
-def decode_span(spec: LayoutSpec, g_span, v_span, meta_row) -> DecodedPartition:
-    """g_span (fetch_blocks, gblk) i32; v_span (fetch_blocks, vblk) f32."""
+def decode_span(spec: LayoutSpec, g_span, v_span, meta_row, qs_span=None):
+    """g_span (fetch_blocks, gblk) i32; v_span (fetch_blocks, vblk) f32,
+    or int8 codes when ``qs_span`` (fetch_blocks, n_qgroups) f32 holds
+    their codebook scales — the quantized tier's span.
+
+    Returns (DecodedPartition with f32 vectors, rows (np_max + ov_cap,)
+    i32): ``rows`` are the slots' exact-row addresses into
+    ``vec_buf.reshape(-1, dim)``, what the int8 tier's re-rank reads.
+    """
     side = meta_row[MT_SIDE]
     n_base = meta_row[MT_N_BASE]
     gflat = g_span.reshape(-1)
     vflat = v_span.reshape(-1)
+    base_blk = side * spec.ov_blocks         # first block of the base data
+    ov_blk = (1 - side) * spec.data_blocks   # first block of the overflow
 
-    data_g = lax.dynamic_slice(gflat, (side * spec.ov_blocks * spec.gblk,),
+    data_g = lax.dynamic_slice(gflat, (base_blk * spec.gblk,),
                                (spec.np_max * (spec.deg + 1),))
     adjacency = data_g[: spec.np_max * spec.deg].reshape(spec.np_max, spec.deg)
     base_gids = data_g[spec.np_max * spec.deg:]
+    ov_gids = lax.dynamic_slice(gflat, (ov_blk * spec.gblk,), (spec.ov_cap,))
 
-    ov_goff = (1 - side) * spec.data_blocks * spec.gblk
-    ov_gids = lax.dynamic_slice(gflat, (ov_goff,), (spec.ov_cap,))
-
-    data_v = lax.dynamic_slice(vflat, (side * spec.ov_blocks * spec.vblk,),
-                               (spec.np_max * spec.dim,))
-    base_vecs = data_v.reshape(spec.np_max, spec.dim)
-    ov_voff = (1 - side) * spec.data_blocks * spec.vblk
-    ov_vecs = lax.dynamic_slice(vflat, (ov_voff,),
-                                (spec.ov_cap * spec.dim,)).reshape(
-                                    spec.ov_cap, spec.dim)
+    def vectors(blk, n):
+        off = blk * spec.vblk
+        x = lax.dynamic_slice(vflat, (off,), (n * spec.dim,))
+        if qs_span is not None:      # dequantize: one multiply per group
+            g = spec.quant_group
+            scales = lax.dynamic_slice(qs_span.reshape(-1), (off // g,),
+                                       (n * spec.dim // g,))
+            x = x.astype(jnp.float32).reshape(-1, g) * scales[:, None]
+        return x.reshape(n, spec.dim)
 
     cnt_a, cnt_b = meta_row[MT_OV_A], meta_row[MT_OV_B]
     ov_idx = jnp.arange(spec.ov_cap)
@@ -65,175 +76,17 @@ def decode_span(spec: LayoutSpec, g_span, v_span, meta_row) -> DecodedPartition:
     ov_mine = jnp.where(side == 0, ov_idx < cnt_a,
                         ov_idx >= spec.ov_cap - cnt_b)
     base_valid = jnp.arange(spec.np_max) < n_base
-    return DecodedPartition(
-        vectors=jnp.concatenate([base_vecs, ov_vecs], axis=0),
-        adjacency=adjacency[None],
-        gids=jnp.concatenate([base_gids, ov_gids]),
-        valid=jnp.concatenate([base_valid, ov_mine]),
-        entry=meta_row[MT_ENTRY],
-    )
 
-
-def search_decoded_scan(part: DecodedPartition, q, k: int):
-    """Exact top-k over every valid vector (base + overflow) — the
-    beyond-paper MXU path.  Returns (dists (k,), global ids (k,))."""
-    d = jnp.sum(jnp.square(part.vectors - q[None, :]), axis=-1)
-    d = jnp.where(part.valid, d, jnp.inf)
-    nd, ni = lax.top_k(-d, k)
-    return -nd, part.gids[ni]
-
-
-def search_decoded_graph(part: DecodedPartition, q, k: int, ef: int):
-    """Paper-faithful: beam-search the sub-HNSW graph over the base
-    vectors, then brute-scan the (tiny) live overflow slice and merge —
-    exactly how the paper covers not-yet-relinked inserted vectors."""
-    np_max = part.adjacency.shape[1]
-    bd, bi = S.beam_search(part.vectors[:np_max], part.adjacency, q,
-                           part.entry, ef=max(ef, k), n_levels=1)
-    bd = jnp.where((bi >= 0) & part.valid[jnp.maximum(bi, 0)], bd, jnp.inf)
-    base_d, base_i = bd[:k], jnp.where(jnp.isfinite(bd[:k]),
-                                       part.gids[jnp.maximum(bi[:k], 0)], -1)
-    ov_vecs = part.vectors[np_max:]
-    ov_d = jnp.sum(jnp.square(ov_vecs - q[None, :]), axis=-1)
-    ov_d = jnp.where(part.valid[np_max:], ov_d, jnp.inf)
-    kk = min(k, ov_vecs.shape[0])
-    od, oi = lax.top_k(-ov_d, kk)
-    og = part.gids[np_max + oi]
-    return S.merge_topk(base_d, base_i, -od, jnp.where(jnp.isfinite(-od), og, -1), k)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("spec", "k", "ef", "mode", "n_lanes"),
-                   donate_argnums=(5, 6))
-def serve_and_merge(spec: LayoutSpec, cache_g, cache_v, meta_table, queries,
-                    run_d, run_g, pair_qi, pair_pids, pair_slots, pair_ranks,
-                    pair_valid, *, k: int, ef: int, mode: str, n_lanes: int):
-    """One round, fused: per-pair top-k inside the pair's partition, then a
-    single vectorized scatter-merge into the batch's running top-k.
-
-    Replaces the host loop that merged each pair's ``(k,)`` list into its
-    query's running list one ``np.argsort`` at a time.  All staging is
-    device-side gathers from arrays resident since batch start:
-
-    meta_table: (n_partitions, META_COLS) — the whole cached table; each
-                pair gathers its own row (no per-round host rebuild)
-    queries:    (B, D) — the full query batch; gathered by ``pair_qi``
-    run_d/run_g:(B, k) running top-k carried across rounds (donated)
-    pair_qi:    (n_pairs,) query index; padding lanes point at row B so
-                the ``(B+1, n_lanes, k)`` scatter drops them
-    pair_ranks: (n_pairs,) merge lane — occurrence index of the pair's
-                query within this round (unique per (query, round))
-    Returns the updated (run_d, run_g): (B, k) each.
-
-    Merge semantics are identical to folding the pairs in order through a
-    stable sort (stable argsort over [running | lane 0 | lane 1 | ...] is
-    associative with the sequential stable merges the host loop did), so
-    results are bit-identical to the old path.
-    """
-    with jax.named_scope("serve/decode"):
-        rows = meta_table[pair_pids]
-        qs = queries[pair_qi]      # padding qi == B clamps; masked below
-
-    def one(slot, row, q, ok):
-        with jax.named_scope("serve/decode"):
-            part = decode_span(spec, cache_g[slot], cache_v[slot], row)
-        with jax.named_scope("serve/walk"):
-            if mode == "graph":
-                d, g = search_decoded_graph(part, q, k, ef)
-            else:
-                d, g = search_decoded_scan(part, q, k)
-            return jnp.where(ok, d, jnp.inf), jnp.where(ok, g, -1)
-
-    d, g = jax.vmap(one)(pair_slots, rows, qs, pair_valid)
-    with jax.named_scope("serve/merge"):
-        return merge_ranked(run_d, run_g, pair_qi, pair_ranks, d, g,
-                            n_lanes=n_lanes)
-
-
-@functools.partial(jax.jit, static_argnames=("n_lanes",))
-def merge_ranked(run_d, run_g, pair_qi, pair_ranks, d, g, *, n_lanes: int):
-    """Scatter-merge per-pair top-k lists into the running per-query top-k.
-
-    Each pair lands in merge lane ``(pair_qi, pair_ranks)`` of a
-    ``(B+1, n_lanes, k)`` buffer (row B is the dump row for padding pairs),
-    then one stable argsort per query takes the new top-k.  Equivalent to
-    folding the pairs through sequential stable merges.
-    """
-    k = run_d.shape[1]
-    B = run_d.shape[0]
-    buf_d = jnp.full((B + 1, n_lanes, k), jnp.inf, run_d.dtype)
-    buf_g = jnp.full((B + 1, n_lanes, k), -1, run_g.dtype)
-    buf_d = buf_d.at[pair_qi, pair_ranks].set(d)
-    buf_g = buf_g.at[pair_qi, pair_ranks].set(g.astype(run_g.dtype))
-    all_d = jnp.concatenate([run_d, buf_d[:B].reshape(B, n_lanes * k)], axis=1)
-    all_g = jnp.concatenate([run_g, buf_g[:B].reshape(B, n_lanes * k)], axis=1)
-    order = jnp.argsort(all_d, axis=1, stable=True)[:, :k]
-    return (jnp.take_along_axis(all_d, order, axis=1),
-            jnp.take_along_axis(all_g, order, axis=1))
-
-
-# ------------------------------------------------------------ quantized tier
-#
-# The staged (quant=int8) search path: stage 1 decodes QUANTIZED spans
-# resident in the large quantized tier into the same DecodedPartition
-# view (dequantize = one fused multiply) and pools per-query candidates
-# (distance, gid, exact-row address, pid); stage 2 gathers only the
-# candidate rows in full precision and re-ranks to the final top-k.
-# Everything below is additive — the full-precision serve path above is
-# untouched so quant="none" stays bit-identical.
-
-
-def decode_quant_span(spec: LayoutSpec, g_span, qv_span, qs_span, meta_row):
-    """Quantized twin of ``decode_span``.
-
-    g_span (fetch_blocks, gblk) i32; qv_span (fetch_blocks, vblk) int8;
-    qs_span (fetch_blocks, n_qgroups) f32.  Returns (DecodedPartition
-    with dequantized f32 vectors, rows (np_max + ov_cap,) i32) where
-    ``rows`` are exact-row addresses into ``vec_buf.reshape(-1, dim)``
-    — what stage 2 fetches for re-ranking.
-    """
-    g = spec.quant_group
-    side = meta_row[MT_SIDE]
-    n_base = meta_row[MT_N_BASE]
-    gflat = g_span.reshape(-1)
-    qvflat = qv_span.reshape(-1).astype(jnp.float32)
-    qsflat = qs_span.reshape(-1)
-
-    data_g = lax.dynamic_slice(gflat, (side * spec.ov_blocks * spec.gblk,),
-                               (spec.np_max * (spec.deg + 1),))
-    adjacency = data_g[: spec.np_max * spec.deg].reshape(spec.np_max, spec.deg)
-    base_gids = data_g[spec.np_max * spec.deg:]
-    ov_goff = (1 - side) * spec.data_blocks * spec.gblk
-    ov_gids = lax.dynamic_slice(gflat, (ov_goff,), (spec.ov_cap,))
-
-    def dequant(flat_off_floats, n_vecs):
-        codes = lax.dynamic_slice(qvflat, (flat_off_floats,),
-                                  (n_vecs * spec.dim,))
-        scales = lax.dynamic_slice(qsflat, (flat_off_floats // g,),
-                                   (n_vecs * spec.dim // g,))
-        x = codes.reshape(-1, g) * scales[:, None]
-        return x.reshape(n_vecs, spec.dim)
-
-    base_vecs = dequant(side * spec.ov_blocks * spec.vblk, spec.np_max)
-    ov_vecs = dequant((1 - side) * spec.data_blocks * spec.vblk, spec.ov_cap)
-
-    cnt_a, cnt_b = meta_row[MT_OV_A], meta_row[MT_OV_B]
-    ov_idx = jnp.arange(spec.ov_cap)
-    ov_mine = jnp.where(side == 0, ov_idx < cnt_a,
-                        ov_idx >= spec.ov_cap - cnt_b)
-    base_valid = jnp.arange(spec.np_max) < n_base
-
-    # exact-row addresses: vblk = slot_vecs * dim, so row r of the region
-    # lives at flat row index block * slot_vecs + local offset
+    # vblk = slot_vecs * dim, so row r of the region lives at flat row
+    # index block * slot_vecs + local offset
     blk_start = meta_row[MT_BLK_START]
-    data_row0 = (blk_start + side * spec.ov_blocks) * spec.slot_vecs
-    ov_row0 = (blk_start + (1 - side) * spec.data_blocks) * spec.slot_vecs
-    rows = jnp.concatenate([data_row0 + jnp.arange(spec.np_max),
-                            ov_row0 + jnp.arange(spec.ov_cap)]).astype(
-                                jnp.int32)
-
+    rows = jnp.concatenate([
+        (blk_start + base_blk) * spec.slot_vecs + jnp.arange(spec.np_max),
+        (blk_start + ov_blk) * spec.slot_vecs + jnp.arange(spec.ov_cap),
+    ]).astype(jnp.int32)
     part = DecodedPartition(
-        vectors=jnp.concatenate([base_vecs, ov_vecs], axis=0),
+        vectors=jnp.concatenate([vectors(base_blk, spec.np_max),
+                                 vectors(ov_blk, spec.ov_cap)], axis=0),
         adjacency=adjacency[None],
         gids=jnp.concatenate([base_gids, ov_gids]),
         valid=jnp.concatenate([base_valid, ov_mine]),
@@ -242,99 +95,120 @@ def decode_quant_span(spec: LayoutSpec, g_span, qv_span, qs_span, meta_row):
     return part, rows
 
 
-def _pad_topk(d, i, k: int):
-    """Pad a (kk,) top list to (k,) with inf/-1 when kk < k."""
-    kk = d.shape[0]
-    if kk >= k:
-        return d[:k], i[:k]
-    pad = k - kk
-    return (jnp.concatenate([d, jnp.full((pad,), jnp.inf, d.dtype)]),
-            jnp.concatenate([i, jnp.full((pad,), -1, i.dtype)]))
-
-
-def search_decoded_scan_local(part: DecodedPartition, q, k: int):
-    """Like ``search_decoded_scan`` but returns LOCAL indices (the
-    candidate-pool path needs them to derive exact-row addresses)."""
-    n = part.vectors.shape[0]
+def search_decoded_scan(part: DecodedPartition, q, k: int):
+    """Exact top-k over every valid vector (base + overflow) — the
+    beyond-paper MXU path.  Returns (dists (k,), local ids (k,)), -1
+    where the distance is inf (fewer than k valid vectors)."""
     d = jnp.sum(jnp.square(part.vectors - q[None, :]), axis=-1)
     d = jnp.where(part.valid, d, jnp.inf)
+    n = d.shape[0]
     nd, ni = lax.top_k(-d, min(k, n))
-    return _pad_topk(-nd, ni.astype(jnp.int32), k)
+    if k > n:                        # a candidate pool wider than the span
+        nd = jnp.concatenate([nd, jnp.full((k - n,), -jnp.inf, nd.dtype)])
+        ni = jnp.concatenate([ni, jnp.full((k - n,), -1, ni.dtype)])
+    return -nd, jnp.where(jnp.isfinite(nd), ni, -1)
 
 
-def search_decoded_graph_local(part: DecodedPartition, q, k: int, ef: int):
-    """Like ``search_decoded_graph`` but returns LOCAL indices: beam walk
-    over the base graph + brute scan of the live overflow slice."""
+def search_decoded_graph(part: DecodedPartition, q, k: int, ef: int):
+    """Paper-faithful: beam-search the sub-HNSW graph over the base
+    vectors, then brute-scan the (tiny) live overflow slice and merge —
+    exactly how the paper covers not-yet-relinked inserted vectors.
+    Returns (dists (k,), local ids (k,)), -1 where the distance is inf."""
     np_max = part.adjacency.shape[1]
     bd, bi = S.beam_search(part.vectors[:np_max], part.adjacency, q,
                            part.entry, ef=max(ef, k), n_levels=1)
     bd = jnp.where((bi >= 0) & part.valid[jnp.maximum(bi, 0)], bd, jnp.inf)
-    ov_d = jnp.sum(jnp.square(part.vectors[np_max:] - q[None, :]), axis=-1)
+    ov_vecs = part.vectors[np_max:]
+    ov_d = jnp.sum(jnp.square(ov_vecs - q[None, :]), axis=-1)
     ov_d = jnp.where(part.valid[np_max:], ov_d, jnp.inf)
-    all_d = jnp.concatenate([bd, ov_d])
-    all_i = jnp.concatenate([bi.astype(jnp.int32),
-                             np_max + jnp.arange(ov_d.shape[0],
-                                                 dtype=jnp.int32)])
-    kk = min(k, all_d.shape[0])
-    nd, pos = lax.top_k(-all_d, kk)
-    return _pad_topk(-nd, all_i[pos], k)
+    od, oi = lax.top_k(-ov_d, min(k, ov_vecs.shape[0]))
+    d, li = S.merge_topk(bd[:k], bi[:k], -od, np_max + oi, k)
+    return d, jnp.where(jnp.isfinite(d), li, -1)
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("spec", "m", "ef", "mode", "n_lanes"),
-                   donate_argnums=(6, 7))
-def serve_quant_pool(spec: LayoutSpec, cache_qg, cache_qv, cache_qs,
-                     meta_table, queries, pool_d, pool_p, pair_qi,
-                     pair_pids, pair_slots, pair_ranks, pair_valid, *,
-                     m: int, ef: int, mode: str, n_lanes: int):
-    """Stage-1 round, fused: per-pair top-m inside the pair's QUANTIZED
-    partition, then one scatter-merge into the batch's running candidate
-    pool.  ``pool_d`` (B, m) distances; ``pool_p`` (B, m, 3) int32
-    payload columns [gid, exact_row, pid] carried through the merge.
+                   static_argnames=("spec", "k", "ef", "mode", "n_lanes"),
+                   donate_argnums=(4, 5))
+def serve_and_merge(spec: LayoutSpec, cache, meta_table, queries, run_d,
+                    run_p, pair_qi, pair_pids, pair_slots, pair_ranks,
+                    pair_valid, *, k: int, ef: int, mode: str, n_lanes: int):
+    """One round, fused: per-pair top-k inside the pair's partition, then a
+    single vectorized scatter-merge into the batch's running top-k.
+
+    Replaces the host loop that merged each pair's ``(k,)`` list into its
+    query's running list one ``np.argsort`` at a time.  All staging is
+    device-side gathers from arrays resident since batch start:
+
+    cache:      the tier's slot arrays — (g, v) exact f32 spans, or
+                (g, qv, qs) int8 codes + scales, dequantized on decode
+    meta_table: (n_partitions, META_COLS) — the whole cached table; each
+                pair gathers its own row (no per-round host rebuild)
+    queries:    (B, D) — the full query batch; gathered by ``pair_qi``
+    run_d/run_p:(B, k) running top-k carried across rounds (donated);
+                ``run_p`` holds gids (B, k), or for the int8 tier the
+                payload (B, k, 3) [gid, exact_row, pid] its re-rank needs
+    pair_qi:    (n_pairs,) query index; padding lanes point at row B so
+                the ``(B+1, n_lanes, k)`` scatter drops them
+    pair_ranks: (n_pairs,) merge lane — occurrence index of the pair's
+                query within this round (unique per (query, round))
+    Returns the updated (run_d, run_p).
+
+    Merge semantics are identical to folding the pairs in order through a
+    stable sort (stable argsort over [running | lane 0 | lane 1 | ...] is
+    associative with the sequential stable merges the host loop did), so
+    results are bit-identical to the old path.
     """
-    mrows = meta_table[pair_pids]
-    qs = queries[pair_qi]
+    with jax.named_scope("serve/decode"):
+        mrows = meta_table[pair_pids]
+        qs = queries[pair_qi]      # padding qi == B clamps; masked below
 
     def one(slot, mrow, q, ok, pid):
-        part, rows = decode_quant_span(spec, cache_qg[slot], cache_qv[slot],
-                                       cache_qs[slot], mrow)
-        if mode == "graph":
-            d, li = search_decoded_graph_local(part, q, m, ef)
-        else:
-            d, li = search_decoded_scan_local(part, q, m)
-        live = (li >= 0) & ok & jnp.isfinite(d)
-        safe = jnp.maximum(li, 0)
-        payload = jnp.stack([
-            jnp.where(live, part.gids[safe], -1),
-            jnp.where(live, rows[safe], -1),
-            jnp.where(live, pid, -1),
-        ], axis=-1).astype(jnp.int32)
-        return jnp.where(live, d, jnp.inf), payload
+        with jax.named_scope("serve/decode"):
+            g_span, v_span, *qs_span = (c[slot] for c in cache)
+            part, rows = decode_span(spec, g_span, v_span, mrow, *qs_span)
+        with jax.named_scope("serve/walk"):
+            if mode == "graph":
+                d, li = search_decoded_graph(part, q, k, ef)
+            else:
+                d, li = search_decoded_scan(part, q, k)
+            live = ok & (li >= 0)
+            safe = jnp.maximum(li, 0)
+            p = jnp.where(live, part.gids[safe], -1)
+            if run_p.ndim == 3:      # int8 tier: [gid, exact_row, pid]
+                p = jnp.stack([p, jnp.where(live, rows[safe], -1),
+                               jnp.where(live, pid, -1)], axis=-1)
+            return jnp.where(live, d, jnp.inf), p
 
     d, p = jax.vmap(one)(pair_slots, mrows, qs, pair_valid, pair_pids)
-    return merge_ranked_payload(pool_d, pool_p, pair_qi, pair_ranks, d, p,
-                                n_lanes=n_lanes)
+    with jax.named_scope("serve/merge"):
+        return merge_ranked(run_d, run_p, pair_qi, pair_ranks, d, p,
+                            n_lanes=n_lanes)
 
 
 @functools.partial(jax.jit, static_argnames=("n_lanes",))
-def merge_ranked_payload(run_d, run_p, pair_qi, pair_ranks, d, p, *,
-                         n_lanes: int):
-    """``merge_ranked`` with an (…, P) int payload instead of a single id
-    column — same (B+1, n_lanes, m) scatter + one stable argsort per
-    query, so round grouping never changes the merged result."""
-    B, m = run_d.shape
-    P = run_p.shape[2]
-    buf_d = jnp.full((B + 1, n_lanes, m), jnp.inf, run_d.dtype)
-    buf_p = jnp.full((B + 1, n_lanes, m, P), -1, run_p.dtype)
+def merge_ranked(run_d, run_p, pair_qi, pair_ranks, d, p, *, n_lanes: int):
+    """Scatter-merge per-pair top-k lists into the running per-query top-k.
+
+    Each pair lands in merge lane ``(pair_qi, pair_ranks)`` of a
+    ``(B+1, n_lanes, k)`` buffer (row B is the dump row for padding pairs),
+    then one stable argsort per query takes the new top-k.  Equivalent to
+    folding the pairs through sequential stable merges.  The payload
+    ``run_p`` (B, k, ...) — ids, or any trailing columns — moves with its
+    distance.
+    """
+    B, k = run_d.shape
+    tail = run_p.shape[2:]
+    buf_d = jnp.full((B + 1, n_lanes, k), jnp.inf, run_d.dtype)
+    buf_p = jnp.full((B + 1, n_lanes, k) + tail, -1, run_p.dtype)
     buf_d = buf_d.at[pair_qi, pair_ranks].set(d)
     buf_p = buf_p.at[pair_qi, pair_ranks].set(p.astype(run_p.dtype))
-    all_d = jnp.concatenate([run_d, buf_d[:B].reshape(B, n_lanes * m)],
-                            axis=1)
-    all_p = jnp.concatenate([run_p, buf_p[:B].reshape(B, n_lanes * m, P)],
-                            axis=1)
-    order = jnp.argsort(all_d, axis=1, stable=True)[:, :m]
+    all_d = jnp.concatenate([run_d, buf_d[:B].reshape(B, n_lanes * k)], axis=1)
+    all_p = jnp.concatenate(
+        [run_p, buf_p[:B].reshape((B, n_lanes * k) + tail)], axis=1)
+    order = jnp.argsort(all_d, axis=1, stable=True)[:, :k]
+    order_p = order.reshape(order.shape + (1,) * len(tail))
     return (jnp.take_along_axis(all_d, order, axis=1),
-            jnp.take_along_axis(all_p, order[:, :, None], axis=1))
+            jnp.take_along_axis(all_p, order_p, axis=1))
 
 
 @functools.partial(jax.jit, static_argnames=("dim",))
@@ -359,14 +233,6 @@ def rerank_gathered(vrows, queries, rows, gids, *, k: int):
         nd, ni = lax.top_k(-d, k)
         g = jnp.take_along_axis(gids, ni, axis=1)
         return -nd, jnp.where(jnp.isfinite(-nd), g, -1)
-
-
-def rerank_exact(vec_buf, queries, rows, gids, *, dim: int, k: int):
-    """Fused legacy entry point: gather + re-rank in one call (kept for
-    callers that hold the region buffer directly; the engine now splits
-    this across the pool boundary as gather_rows -> rerank_gathered)."""
-    vrows = gather_rows(vec_buf, rows, dim=dim)
-    return rerank_gathered(vrows, queries, rows, gids, k=k)
 
 
 @functools.partial(jax.jit, static_argnames=("dim", "group"))
@@ -399,17 +265,6 @@ def flat_candidates(flat_map, d, idx, *, m: int):
         return pool_d, pool_p
 
 
-@functools.partial(jax.jit, static_argnames=("spec",),
-                   donate_argnums=(1, 2, 3))
-def write_slots_quant(spec: LayoutSpec, cache_qg, cache_qv, cache_qs,
-                      slot_ids, g_blocks, qv_blocks, qs_blocks):
-    """Install fetched QUANTIZED spans into quant-tier slots."""
-    cache_qg = cache_qg.at[slot_ids].set(g_blocks)
-    cache_qv = cache_qv.at[slot_ids].set(qv_blocks)
-    cache_qs = cache_qs.at[slot_ids].set(qs_blocks)
-    return cache_qg, cache_qv, cache_qs
-
-
 @functools.partial(jax.jit, static_argnames=("spec",))
 def overflow_append_quant(spec: LayoutSpec, qvec_buf, qscale_buf, vec,
                           vec_block, vec_off):
@@ -428,17 +283,16 @@ def overflow_append_quant(spec: LayoutSpec, qvec_buf, qscale_buf, vec,
     return qvec_buf, qscale_buf
 
 
-@functools.partial(jax.jit, static_argnames=("spec",), donate_argnums=(1, 2))
-def write_slots(spec: LayoutSpec, cache_g, cache_v, slot_ids, g_blocks,
-                v_blocks):
+@functools.partial(jax.jit, static_argnames=("spec",), donate_argnums=(1,))
+def write_slots(spec: LayoutSpec, cache, slot_ids, blocks):
     """Install fetched spans into cache slots (functional scatter).
 
-    g_blocks: (n_fetch, fetch_blocks, gblk); slot_ids: (n_fetch,).
+    cache: a tier's slot arrays, (g, v) or (g, qv, qs); blocks: the
+    matching tuple ``read_spans`` returns, (n_fetch, fetch_blocks, ...)
+    each; slot_ids: (n_fetch,).
     """
     with jax.named_scope("fetch/write_slots"):
-        cache_g = cache_g.at[slot_ids].set(g_blocks)
-        cache_v = cache_v.at[slot_ids].set(v_blocks)
-        return cache_g, cache_v
+        return tuple(c.at[slot_ids].set(b) for c, b in zip(cache, blocks))
 
 
 @functools.partial(jax.jit, static_argnames=("spec",))

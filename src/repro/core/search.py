@@ -147,8 +147,7 @@ def scan_partition(part_vectors, q, k: int, n_valid=None):
     """Exact top-k within one loaded partition ((Np, D) padded).
 
     ``n_valid`` masks layout padding / unused overflow slots.  Pure-jnp
-    path; the Pallas MXU kernel (kernels/distance_topk) is the production
-    route — engine.py picks by flag.
+    path.
     """
     d = jnp.sum(jnp.square(part_vectors - q[None, :]), axis=-1)
     if n_valid is not None:

@@ -234,7 +234,7 @@ def gid_tail_offsets(spec: LayoutSpec, side: int):
     """Flat offsets of the two global-id runs inside one span's graph
     blocks (``fetch_blocks * gblk`` int32): the base-gid tail of the data
     region and the overflow gid run — the only graph lanes the scan-mode
-    quant path reads (``device_store.decode_quant_span``)."""
+    quant path reads (``device_store.decode_span`` of an int8 span)."""
     data_off = side * spec.ov_blocks * spec.gblk + spec.np_max * spec.deg
     ov_off = (1 - side) * spec.data_blocks * spec.gblk
     return data_off, ov_off

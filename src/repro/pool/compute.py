@@ -9,9 +9,7 @@ one-sided appends — so swapping the transport (in-process, simulated
 RDMA, and later a real fabric) never changes a line here.
 
 ``core/engine.py DHNSWEngine`` is a thin facade over (ComputeClient +
-pool); the search/insert bodies below are the engine's previous
-monolithic paths re-expressed on the boundary, kept bit-identical for
-``pool="local"``.
+pool); every pool answers bit-identically to ``pool="local"``.
 """
 from __future__ import annotations
 
@@ -34,9 +32,27 @@ from repro.obs.trace import TRACER
 from repro.pool.protocol import MemoryPool
 
 
+def _empty_slots(spec: LA.LayoutSpec, cap: int, quant: bool) -> tuple:
+    """A cache tier's device slot arrays: (g, v) f32 exact spans, or
+    (g, qv, qs) int8 codes + codebook scales for the quantized tier."""
+    g = jnp.full((cap, spec.fetch_blocks, spec.gblk), -1, jnp.int32)
+    if not quant:
+        return g, jnp.zeros((cap, spec.fetch_blocks, spec.vblk), jnp.float32)
+    return (g, jnp.zeros((cap, spec.fetch_blocks, spec.vblk), jnp.int8),
+            jnp.zeros((cap, spec.fetch_blocks, spec.n_qgroups), jnp.float32))
+
+
 class ComputeClient:
     """Plans greedy search against a ``MemoryPool`` (build once, then
     ``search``/``insert`` batches).
+
+    Both cache tiers search through one round loop, ``_rounds``: route,
+    plan against the tier's LRU, then per round fetch the missing spans
+    and run one fused ``serve_and_merge``.  ``quant="none"`` serves the
+    exact tier's top-k straight from it.  ``quant="int8"`` runs it over
+    the quantized tier for a top-m candidate pool (or, dense-resident in
+    scan mode with ``quant_kernel`` on, one flat ``quant_topk`` scan
+    instead), then re-ranks the candidates' exact rows.
 
     Each host step is a ``TRACER.span`` (``compute.route``,
     ``compute.plan``, ``compute.fetch``, ``compute.serve``, ...).  The
@@ -111,15 +127,11 @@ class ComputeClient:
         self._setup_caches(cap)
 
     def _setup_caches(self, cap: int):
-        cfg = self.cfg
-        if cfg.quant == "none":
+        if self.cfg.quant == "none":
             self.tiers = None
             self.cache = SCH.LRUCacheState(cap)
-            spec = self.pool.spec
-            self._cache_g = jnp.full((cap, spec.fetch_blocks, spec.gblk), -1,
-                                     jnp.int32)
-            self._cache_v = jnp.zeros((cap, spec.fetch_blocks, spec.vblk),
-                                      jnp.float32)
+            self._cache = _empty_slots(self.pool.spec, cap, quant=False)
+            self._qcache = None
         else:
             self._setup_quant(cap)
         self._flat_synced = False
@@ -148,16 +160,8 @@ class ComputeClient:
         quant_cap = max(2, int((cap - exact_cap) * pb // qpb))
         self.tiers = SCH.TieredCacheState(quant_cap, exact_cap)
         self.cache = self.tiers.exact   # legacy helpers see the exact tier
-        self._cache_g = jnp.full((exact_cap, spec.fetch_blocks, spec.gblk),
-                                 -1, jnp.int32)
-        self._cache_v = jnp.zeros((exact_cap, spec.fetch_blocks, spec.vblk),
-                                  jnp.float32)
-        self._cache_qg = jnp.full((quant_cap, spec.fetch_blocks, spec.gblk),
-                                  -1, jnp.int32)
-        self._cache_qv = jnp.zeros((quant_cap, spec.fetch_blocks, spec.vblk),
-                                   jnp.int8)
-        self._cache_qs = jnp.zeros(
-            (quant_cap, spec.fetch_blocks, spec.n_qgroups), jnp.float32)
+        self._cache = _empty_slots(spec, exact_cap, quant=False)
+        self._qcache = _empty_slots(spec, quant_cap, quant=True)
 
     def _lookup(self, gids: np.ndarray) -> np.ndarray:
         out = np.zeros((len(gids), self.pool.spec.dim), np.float32)
@@ -182,14 +186,50 @@ class ComputeClient:
         b = b or cfg.b
         if cfg.quant != "none":
             return self._search_quant(queries, k=k, ef=ef, b=b)
-        pool = self.pool
-        spec = pool.spec
         queries = np.asarray(queries, np.float32)
         B = queries.shape[0]
         q_dev = jnp.asarray(queries)
         ledger = NetLedger(cfg.fabric)
         stats = {"meta_s": 0.0, "sub_s": 0.0, "plan_s": 0.0,
                  "n_rounds": 0, "n_pairs": 0}
+        run_d, run_g = self._rounds(q_dev, B, k, ef, b, False, ledger, stats)
+        t0 = time.perf_counter()
+        run_d = np.asarray(jax.block_until_ready(run_d))
+        run_g = np.asarray(run_g).astype(np.int64)
+        stats["sub_s"] += time.perf_counter() - t0
+        return run_d, run_g, self._close_stats(stats, ledger, B)
+
+    def _close_stats(self, stats: dict, ledger: NetLedger, B: int) -> dict:
+        stats["net"] = ledger.as_dict()
+        stats["round_trips_per_query"] = ledger.round_trips / max(B, 1)
+        stats["pool"] = self.pool.snapshot()
+        return stats
+
+    def _rounds(self, q_dev, B: int, width: int, ef: int, b: int,
+                quant: bool, ledger: NetLedger, stats: dict):
+        """The §3.3 round loop of either tier: route, plan the batch
+        against the tier's LRU, then per round fetch the missing spans
+        into slots and fold the round's pairs in with ONE fused
+        ``serve_and_merge`` (all device-side; no host loop over pairs).
+
+        ``quant`` picks the int8 tier: its misses move int8 codes +
+        codebook (and, in graph mode, the adjacency blocks) — 2
+        descriptors per span — and its running list carries the (B,
+        width, 3) payload [gid, exact_row, pid] stage 2 re-ranks.
+        Returns the running (dists (B, width), payload) device arrays;
+        sets ``cache_hits``/``n_fetches`` in ``stats``.
+        """
+        cfg = self.cfg
+        pool = self.pool
+        spec = pool.spec
+        read_kw, tag, saved = {}, {}, 0
+        if quant:
+            include_graph = cfg.search_mode == "graph"
+            read_kw = {"quant": True, "quant_graph": include_graph}
+            tag = {"quant": True}
+            # bytes one quantized span read keeps off the wire
+            saved = (spec.partition_bytes()
+                     - spec.quant_partition_bytes(include_graph=include_graph))
 
         t0 = time.perf_counter()
         with TRACER.span("compute.route", tier="compute", B=B):
@@ -199,44 +239,34 @@ class ComputeClient:
         # plan (compute-instance CPU role)
         t0 = time.perf_counter()
         with TRACER.span("compute.plan", tier="compute") as span:
-            owner_of = getattr(pool, "owner_of_pid", None)
             if cfg.mode == "naive":
                 raw = SCH.naive_plan(pids)
                 # every pair is its own READ round trip (the 3.547 trips/
                 # query); dedup below is compute-only, so movement through
                 # the pool goes uncharged (ledger=None) — already posted
                 pool.post_span_reads(len(raw), ledger=ledger, doorbell=1,
-                                     pids=[p for _, p in raw])
-                uniq = sorted({p for _, p in raw})
-                cache = SCH.LRUCacheState(max(len(uniq), 1))
-                plan = SCH.plan_batch(pids, cache, doorbell=1)
+                                     pids=[p for _, p in raw], **read_kw)
+                ledger.save(len(raw) * saved)
+                lru = SCH.LRUCacheState(max(len({p for _, p in raw}), 1))
+                plan = SCH.plan_batch(pids, lru, doorbell=1)
+                cache = _empty_slots(spec, lru.capacity, quant)
+                fetch_ledger, doorbell = None, 1
             else:
-                plan = SCH.plan_batch(pids, self.cache,
-                                      doorbell=cfg.doorbell,
-                                      owner_of=owner_of)
+                plan = SCH.plan_batch(
+                    pids, self.tiers.quant if quant else self.cache,
+                    doorbell=cfg.doorbell,
+                    owner_of=getattr(pool, "owner_of_pid", None))
+                cache = self._qcache if quant else self._cache
+                fetch_ledger = ledger
+                doorbell = 1 if cfg.mode == "no_doorbell" else cfg.doorbell
             span.set(rounds=len(plan.rounds), fetches=plan.n_fetches,
                      hits=plan.n_cache_hits)
         stats["plan_s"] = time.perf_counter() - t0
 
-        # rounds: fetch -> serve -> merge (all device-side; the running
-        # top-k is carried as (B, k) device arrays and each round folds
-        # in with ONE fused scatter-merge — no host loop over pairs)
         mt_dev = pool.read_meta()
-        run_d = jnp.full((B, k), jnp.inf, jnp.float32)
-        run_g = jnp.full((B, k), -1, jnp.int32)
-        cache_state = cache if cfg.mode == "naive" else self.cache
-        if cfg.mode == "naive":
-            cache_g = jnp.full((cache_state.capacity, spec.fetch_blocks,
-                                spec.gblk), -1, jnp.int32)
-            cache_v = jnp.zeros((cache_state.capacity, spec.fetch_blocks,
-                                 spec.vblk), jnp.float32)
-            fetch_ledger = None          # naive pre-charged every demand
-            fetch_doorbell = 1
-        else:
-            cache_g, cache_v = self._cache_g, self._cache_v
-            fetch_ledger = ledger
-            fetch_doorbell = 1 if cfg.mode == "no_doorbell" else cfg.doorbell
-
+        run_d = jnp.full((B, width), jnp.inf, jnp.float32)
+        run_p = jnp.full((B, width, 3) if quant else (B, width), -1,
+                         jnp.int32)
         for rnd in plan.rounds:
             stats["n_rounds"] += 1
             with TRACER.span("compute.round", tier="compute",
@@ -244,46 +274,43 @@ class ComputeClient:
                              pairs=int(len(rnd.serve_pairs))):
                 if len(rnd.fetch_pids):
                     with TRACER.span("compute.fetch", tier="compute",
-                                     spans=int(len(rnd.fetch_pids))):
-                        g_blocks, v_blocks = pool.read_spans(
+                                     spans=int(len(rnd.fetch_pids)), **tag):
+                        blocks = pool.read_spans(
                             rnd.fetch_pids, ledger=fetch_ledger,
-                            doorbell=fetch_doorbell)
-                        slots = jnp.asarray(rnd.fetch_slots, jnp.int32)
-                        cache_g, cache_v = DS.write_slots(
-                            spec, cache_g, cache_v, slots, g_blocks,
-                            v_blocks)
+                            doorbell=doorbell, **read_kw)
+                        if fetch_ledger is not None:
+                            ledger.save(len(rnd.fetch_pids) * saved)
+                        cache = DS.write_slots(
+                            spec, cache,
+                            jnp.asarray(rnd.fetch_slots, jnp.int32), blocks)
                 if not len(rnd.serve_pairs):
                     continue
                 t0 = time.perf_counter()
                 n = len(rnd.serve_pairs)
-                with TRACER.span("compute.serve", tier="compute", pairs=n):
+                with TRACER.span("compute.serve", tier="compute", pairs=n,
+                                 **tag):
                     npad = pow2_pad(n)
                     qi, ppid, pslot, prank, valid = rnd.serve_tensors(npad,
                                                                       B)
                     # n_lanes is fixed at b (a query never has more than
                     # b pairs in one round) so recompiles depend only on
                     # (B, npad)
-                    run_d, run_g = DS.serve_and_merge(
-                        spec, cache_g, cache_v, mt_dev, q_dev, run_d, run_g,
+                    run_d, run_p = DS.serve_and_merge(
+                        spec, cache, mt_dev, q_dev, run_d, run_p,
                         jnp.asarray(qi), jnp.asarray(ppid),
                         jnp.asarray(pslot), jnp.asarray(prank),
-                        jnp.asarray(valid), k=k, ef=ef,
+                        jnp.asarray(valid), k=width, ef=ef,
                         mode=cfg.search_mode, n_lanes=b)
                 stats["sub_s"] += time.perf_counter() - t0
                 stats["n_pairs"] += n
-
-        t0 = time.perf_counter()
-        run_d = np.asarray(jax.block_until_ready(run_d))
-        run_g = np.asarray(run_g).astype(np.int64)
-        stats["sub_s"] += time.perf_counter() - t0
         if cfg.mode != "naive":
-            self._cache_g, self._cache_v = cache_g, cache_v
-        stats["net"] = ledger.as_dict()
-        stats["round_trips_per_query"] = ledger.round_trips / max(B, 1)
+            if quant:
+                self._qcache = cache
+            else:
+                self._cache = cache
         stats["cache_hits"] = plan.n_cache_hits
         stats["n_fetches"] = plan.n_fetches
-        stats["pool"] = pool.snapshot()
-        return run_d, run_g, stats
+        return run_d, run_p
 
     # ------------------------------------------------------ staged search
 
@@ -304,9 +331,7 @@ class ComputeClient:
         cfg = self.cfg
         pool = self.pool
         spec = pool.spec
-        include_graph = cfg.search_mode == "graph"
         pb = spec.partition_bytes()
-        qpb = spec.quant_partition_bytes(include_graph=include_graph)
         row_b = spec.row_bytes()
         m = max(int(cfg.rerank_m) or 2 * k, k)
         queries = np.asarray(queries, np.float32)
@@ -318,12 +343,11 @@ class ComputeClient:
                  "rerank_m": m}
 
         if self._flat_kernel_active():
-            pool_d, pool_p, plan = self._stage1_flat(q_dev, B, m, ledger,
-                                                     stats)
-            tiers = self.tiers
+            pool_d, pool_p = self._stage1_flat(q_dev, B, m, ledger, stats)
         else:
-            pool_d, pool_p, plan, tiers = self._stage1_pairs(
-                q_dev, B, m, ef, b, qpb, pb, ledger, stats)
+            pool_d, pool_p = self._rounds(q_dev, B, m, max(ef, m), b, True,
+                                          ledger, stats)
+        tiers = self.tiers
 
         # stage-2 accounting: pool payload -> row fetch plan
         t0 = time.perf_counter()
@@ -368,11 +392,10 @@ class ComputeClient:
                     tiers.note_rerank_miss(int(p), cnt)
                     if tiers.should_admit(int(p), row_b, pb):
                         slot, _ = tiers.admit_exact(int(p))
-                        g_b, v_b = pool.read_spans(np.array([int(p)]),
-                                                   ledger=ledger, doorbell=1)
-                        self._cache_g, self._cache_v = DS.write_slots(
-                            spec, self._cache_g, self._cache_v,
-                            jnp.asarray([slot], jnp.int32), g_b, v_b)
+                        self._cache = DS.write_slots(
+                            spec, self._cache, jnp.asarray([slot], jnp.int32),
+                            pool.read_spans(np.array([int(p)]),
+                                            ledger=ledger, doorbell=1))
                         n_admitted += 1
                 stats["rerank_rows"] = int((~hit).sum())
                 stats["rerank_hit_rows"] = int(hit.sum())
@@ -390,113 +413,7 @@ class ComputeClient:
         run_g = np.asarray(run_g).astype(np.int64)
         stats["sub_s"] += time.perf_counter() - t0
 
-        stats["net"] = ledger.as_dict()
-        stats["round_trips_per_query"] = ledger.round_trips / max(B, 1)
-        stats["cache_hits"] = plan["n_cache_hits"]
-        stats["n_fetches"] = plan["n_fetches"]
-        stats["pool"] = pool.snapshot()
-        return run_d, run_g, stats
-
-    def _stage1_pairs(self, q_dev, B: int, m: int, ef: int, b: int,
-                      qpb: int, pb: int, ledger, stats):
-        """Per-pair stage 1 (the jnp fallback): plan against the
-        quantized tier with the §3.3 round machinery and pool top-m
-        candidates via fused per-round scatter-merges."""
-        cfg = self.cfg
-        pool = self.pool
-        spec = pool.spec
-        include_graph = cfg.search_mode == "graph"
-
-        t0 = time.perf_counter()
-        with TRACER.span("compute.route", tier="compute", B=B):
-            pids = self._route(q_dev, b)
-        stats["meta_s"] = time.perf_counter() - t0
-
-        # stage-1 plan against the quantized tier.  A quantized span
-        # read moves the codes + codebook (and, in graph mode, the
-        # adjacency blocks): 2 descriptors per span
-        t0 = time.perf_counter()
-        with TRACER.span("compute.plan", tier="compute") as span:
-            if cfg.mode == "naive":
-                raw = SCH.naive_plan(pids)
-                pool.post_span_reads(len(raw), ledger=ledger, doorbell=1,
-                                     quant=True, quant_graph=include_graph,
-                                     pids=[p for _, p in raw])
-                ledger.save(len(raw) * (pb - qpb))
-                uniq = sorted({p for _, p in raw})
-                tiers = SCH.TieredCacheState(max(len(uniq), 1), 1)
-                plan = SCH.plan_batch(pids, tiers.quant, doorbell=1)
-            else:
-                tiers = self.tiers
-                plan = SCH.plan_batch(pids, tiers.quant,
-                                      doorbell=cfg.doorbell,
-                                      owner_of=getattr(pool, "owner_of_pid",
-                                                       None))
-            span.set(rounds=len(plan.rounds), fetches=plan.n_fetches,
-                     hits=plan.n_cache_hits)
-        stats["plan_s"] = time.perf_counter() - t0
-
-        # stage-1 rounds: fetch quantized spans -> pool candidates
-        mt_dev = pool.read_meta()
-        pool_d = jnp.full((B, m), jnp.inf, jnp.float32)
-        pool_p = jnp.full((B, m, 3), -1, jnp.int32)
-        if cfg.mode == "naive":
-            qcap = tiers.quant.capacity
-            cache_qg = jnp.full((qcap, spec.fetch_blocks, spec.gblk), -1,
-                                jnp.int32)
-            cache_qv = jnp.zeros((qcap, spec.fetch_blocks, spec.vblk),
-                                 jnp.int8)
-            cache_qs = jnp.zeros((qcap, spec.fetch_blocks, spec.n_qgroups),
-                                 jnp.float32)
-            fetch_ledger = None
-            fetch_doorbell = 1
-        else:
-            cache_qg, cache_qv, cache_qs = (self._cache_qg, self._cache_qv,
-                                            self._cache_qs)
-            fetch_ledger = ledger
-            fetch_doorbell = 1 if cfg.mode == "no_doorbell" else cfg.doorbell
-
-        for rnd in plan.rounds:
-            stats["n_rounds"] += 1
-            with TRACER.span("compute.round", tier="compute",
-                             fetch=int(len(rnd.fetch_pids)),
-                             pairs=int(len(rnd.serve_pairs))):
-                if len(rnd.fetch_pids):
-                    with TRACER.span("compute.fetch", tier="compute",
-                                     spans=int(len(rnd.fetch_pids)),
-                                     quant=True):
-                        g_blocks, qv_blocks, qs_blocks = pool.read_spans(
-                            rnd.fetch_pids, ledger=fetch_ledger,
-                            doorbell=fetch_doorbell, quant=True,
-                            quant_graph=include_graph)
-                        if fetch_ledger is not None:
-                            ledger.save(len(rnd.fetch_pids) * (pb - qpb))
-                        slots = jnp.asarray(rnd.fetch_slots, jnp.int32)
-                        cache_qg, cache_qv, cache_qs = DS.write_slots_quant(
-                            spec, cache_qg, cache_qv, cache_qs, slots,
-                            g_blocks, qv_blocks, qs_blocks)
-                if not len(rnd.serve_pairs):
-                    continue
-                t0 = time.perf_counter()
-                n = len(rnd.serve_pairs)
-                with TRACER.span("compute.serve", tier="compute", pairs=n,
-                                 quant=True):
-                    npad = pow2_pad(n)
-                    qi, ppid, pslot, prank, valid = rnd.serve_tensors(npad,
-                                                                      B)
-                    pool_d, pool_p = DS.serve_quant_pool(
-                        spec, cache_qg, cache_qv, cache_qs, mt_dev, q_dev,
-                        pool_d, pool_p, jnp.asarray(qi), jnp.asarray(ppid),
-                        jnp.asarray(pslot), jnp.asarray(prank),
-                        jnp.asarray(valid), m=m, ef=max(ef, m),
-                        mode=cfg.search_mode, n_lanes=b)
-                stats["sub_s"] += time.perf_counter() - t0
-                stats["n_pairs"] += n
-        if cfg.mode != "naive":
-            self._cache_qg, self._cache_qv, self._cache_qs = (
-                cache_qg, cache_qv, cache_qs)
-        return pool_d, pool_p, {"n_cache_hits": plan.n_cache_hits,
-                                "n_fetches": plan.n_fetches}, tiers
+        return run_d, run_g, self._close_stats(stats, ledger, B)
 
     # ------------------------------------------------ flat stage-1 (kernel)
 
@@ -589,9 +506,9 @@ class ComputeClient:
         stats["flat_rows"] = int(self._flat_n)
         stats["stage1_merge_rounds"] = rounds
         stats["stage1_tiles"] = tiles
-        return pool_d, pool_p, {
-            "n_cache_hits": 0 if cold else B,
-            "n_fetches": self.pool.spec.n_partitions if cold else 0}
+        stats["cache_hits"] = 0 if cold else B
+        stats["n_fetches"] = self.pool.spec.n_partitions if cold else 0
+        return pool_d, pool_p
 
     # ------------------------------------------------------------ insert
 
@@ -699,9 +616,4 @@ class ComputeClient:
                                   M0=self.cfg.sub_M0,
                                   ef_construction=self.cfg.ef_construction))
         self.pool.adopt(store)
-        if self.tiers is not None:
-            self._setup_quant(self._cap0)
-        else:
-            cap = self.cache.capacity
-            self._setup_caches(cap)
-        self._flat_synced = False
+        self._setup_caches(self._cap0)

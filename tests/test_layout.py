@@ -90,7 +90,7 @@ def test_all_partitions_roundtrip(store_and_meta):
     spec = store.spec
     for pid in range(spec.n_partitions):
         ids = LA.partition_gids(store, pid)
-        part = DS.decode_span(
+        part, _ = DS.decode_span(
             spec, jnp.asarray(store.graph_buf[store.span_block_ids(pid)]),
             jnp.asarray(store.vec_buf[store.span_block_ids(pid)]),
             jnp.asarray(store.meta_table[pid]))
@@ -136,7 +136,7 @@ def test_insert_into_overflow_and_read_back(store_and_meta):
     assert slot >= 0
     assert 999_999 in LA.overflow_gids(store, pid).tolist()
     # one contiguous span fetch now returns the inserted vector too
-    part = DS.decode_span(
+    part, _ = DS.decode_span(
         spec, jnp.asarray(store.graph_buf[store.span_block_ids(pid)]),
         jnp.asarray(store.vec_buf[store.span_block_ids(pid)]),
         jnp.asarray(store.meta_table[pid]))
@@ -147,13 +147,67 @@ def test_insert_into_overflow_and_read_back(store_and_meta):
     assert np.allclose(np.asarray(part.vectors)[j[0]], vec)
     # the PARTNER's fetch must NOT claim this vector as its own
     partner = pid ^ 1
-    ppart = DS.decode_span(
+    ppart, _ = DS.decode_span(
         spec, jnp.asarray(store.graph_buf[store.span_block_ids(partner)]),
         jnp.asarray(store.vec_buf[store.span_block_ids(partner)]),
         jnp.asarray(store.meta_table[partner]))
     pg = np.asarray(ppart.gids)
     pv = np.asarray(ppart.valid)
     assert not ((pg == 999_999) & pv).any()
+
+
+@pytest.fixture(scope="module")
+def quant_store_both_sides(sift_small):
+    """A store with an int8 mirror and live overflow on BOTH partners of
+    group 0 (two inserts from side A, three from side B)."""
+    data = sift_small.data[:800]
+    meta = build_meta(data, 8, seed=0)
+    store = build_store(data, meta, ov_cap=8)
+    LA.attach_quant_mirror(store, 32)
+    vecs = dict(enumerate(data))
+    for j, pid in enumerate((0, 0, 1, 1, 1)):
+        vecs[50_000 + j] = data[j] + 0.25
+        assert LA.insert_vector(store, vecs[50_000 + j], 50_000 + j, pid) >= 0
+    LA.refresh_quant_group(store, 0)
+    return store, vecs
+
+
+@pytest.mark.parametrize("side", [0, 1])
+def test_int8_decode_matches_exact_decode(quant_store_both_sides, side):
+    """The int8 tier's decode of a span: the exact decode's gids and
+    ``valid``, the codec's dequantization of the same rows, and ``rows``
+    that address the exact vectors of those gids."""
+    import jax.numpy as jnp
+    from repro.core import device_store as DS
+    from repro.quant.codec import dequantize_groups
+    store, vecs = quant_store_both_sides
+    spec = store.spec
+    pid = side                           # group 0: pid 0 is A, pid 1 is B
+    assert int(store.meta_table[pid, LA.MT_SIDE]) == side
+    ids = store.span_block_ids(pid)
+    g_span = jnp.asarray(store.graph_buf[ids])
+    mrow = jnp.asarray(store.meta_table[pid])
+    exact, rows_e = DS.decode_span(spec, g_span,
+                                   jnp.asarray(store.vec_buf[ids]), mrow)
+    quant, rows = DS.decode_span(spec, g_span,
+                                 jnp.asarray(store.qvec_buf[ids]), mrow,
+                                 jnp.asarray(store.qscale_buf[ids]))
+    gids, valid = np.asarray(quant.gids), np.asarray(quant.valid)
+    assert np.array_equal(gids, np.asarray(exact.gids))
+    assert np.array_equal(valid, np.asarray(exact.valid))
+    assert np.array_equal(np.asarray(rows), np.asarray(rows_e))
+    # live overflow of this side only: 2 inserts on A, 3 on B
+    assert int(valid[spec.np_max:].sum()) == (2, 3)[side]
+
+    live_rows = np.asarray(rows)[valid]
+    codes = store.qvec_buf.reshape(-1, spec.dim)[live_rows]
+    scales = store.qscale_buf.reshape(-1, spec.dim // 32)[live_rows]
+    assert np.array_equal(np.asarray(quant.vectors)[valid],
+                          dequantize_groups(codes, scales, 32))
+    exact_rows = np.asarray(DS.gather_rows(
+        jnp.asarray(store.vec_buf), jnp.asarray(live_rows), dim=spec.dim))
+    assert np.array_equal(exact_rows,
+                          np.stack([vecs[int(g)] for g in gids[valid]]))
 
 
 def test_shared_overflow_fills_from_both_ends(sift_small):

@@ -325,8 +325,8 @@ def test_flat_stage1_payload_matches_eager_oracle(qds, eng_flat, monkeypatch,
     monkeypatch.setattr(ops, "quant_topk", spy)
     q = jnp.asarray(qds.queries[:8])
     stats = {"sub_s": 0.0}
-    pool_d, pool_p, _ = client._stage1_flat(q, 8, m, NetLedger(RDMA_100G),
-                                            stats)
+    pool_d, pool_p = client._stage1_flat(q, 8, m, NetLedger(RDMA_100G),
+                                         stats)
     assert stats["stage1_map_uploads"] == 0
     host_map = np.asarray(client._flat_map)
     want_d, want_p = _parent_payload(host_map, seen["d"], seen["idx"], m)

@@ -272,28 +272,32 @@ def _numpy_fold_merge(run_d, run_g, qi, d, g):
 
 
 if HAVE_HYPOTHESIS:
+    @pytest.mark.parametrize("width", [None, 3],
+                             ids=["ids", "payload3"])
     @given(seed=st.integers(0, 10_000), B=st.just(9), k=st.just(8),
            n=st.just(21))
     @settings(max_examples=40, deadline=None)
-    def test_merge_ranked_matches_numpy_fold(seed, B, k, n):
+    def test_merge_ranked_matches_numpy_fold(width, seed, B, k, n):
         """Property: the fused device scatter-merge == the numpy
         sequential fold, ties included (fixed shapes -> one XLA compile
-        across all examples)."""
+        across all examples), for a (B, k) id list and for the int8
+        tier's (B, k, 3) [gid, row, pid] payload."""
         import jax.numpy as jnp
 
         from repro.core.device_store import merge_ranked
         from repro.core.scheduler import _pair_ranks
 
+        tail = () if width is None else (width,)
         rng = np.random.default_rng(seed)
         run_d = np.sort(rng.standard_normal((B, k)).astype(np.float32) ** 2,
                         axis=1)
-        run_g = rng.integers(0, 1000, (B, k)).astype(np.int32)
+        run_g = rng.integers(0, 1000, (B, k) + tail).astype(np.int32)
         qi = rng.integers(0, B, n)
         d = np.sort(rng.standard_normal((n, k)).astype(np.float32) ** 2,
                     axis=1)
         if n and rng.random() < 0.5:     # force exact cross-list ties
             d[0] = run_d[int(qi[0])]
-        g = rng.integers(1000, 2000, (n, k)).astype(np.int32)
+        g = rng.integers(1000, 2000, (n, k) + tail).astype(np.int32)
 
         want_d, want_g = _numpy_fold_merge(run_d, run_g, qi, d, g)
         ranks = _pair_ranks(np.stack([qi, np.zeros(n, np.int64)], axis=1))
@@ -305,5 +309,7 @@ if HAVE_HYPOTHESIS:
         assert np.array_equal(np.asarray(got_d), want_d)
         assert np.array_equal(np.asarray(got_g), want_g)
 else:
-    def test_merge_ranked_matches_numpy_fold():
+    @pytest.mark.parametrize("width", [None, 3],
+                             ids=["ids", "payload3"])
+    def test_merge_ranked_matches_numpy_fold(width):
         pytest.importorskip("hypothesis")

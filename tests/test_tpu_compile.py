@@ -1,9 +1,11 @@
-"""Main-path Pallas kernels compile for a described TPU v5e chip.
+"""Main-path Pallas kernels and the device serve round compile for a
+described TPU v5e chip.
 
 Interpret mode (every other kernel test) cannot see the TPU compiler's
-tiling and layout rules; these tests lower and compile each kernel at
-the widths the served path uses, for one chip of a described ``v5e:2x2``
-topology, with no chip attached.  The topology is described inside a
+tiling and layout rules; these tests lower and compile each kernel, and
+the serve round of both cache tiers, at the widths the served path
+uses, for one chip of a described ``v5e:2x2`` topology, with no chip
+attached.  The topology is described inside a
 fixture (never at import) so every xdist worker collects the same tests.
 """
 import os
@@ -13,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import device_store as DS
 from repro.core.layout import LayoutSpec
 from repro.kernels.distance_topk.kernel import distance_topk_pallas
 from repro.kernels.gather_blocks.kernel import gather_blocks_pallas
@@ -23,6 +26,10 @@ from repro.kernels.quant_topk.ops import _quant_topk_jit
 SIFT_SPEC = LayoutSpec(dim=128, deg=16, np_max=4096, ov_cap=512,
                        slot_vecs=64, n_partitions=500)
 N_ROWS = 1 << 20
+# the SIFT1M graph cell's store at its served size: 1M rows over 500
+# partitions pad every span to np_max 10,118 rows
+GRAPH_SPEC = LayoutSpec(dim=128, deg=16, np_max=10118, ov_cap=2023,
+                        slot_vecs=64, n_partitions=500, quant_group=32)
 
 
 @pytest.fixture(scope="module")
@@ -93,3 +100,32 @@ def test_distance_topk_compiles_for_v5e(one_chip):
                        _shape(one_chip, (N_ROWS, 128), jnp.float32),
                        _shape(one_chip, (), jnp.int32)).compile()
     assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("quant,slots", [(False, 50), (True, 150)],
+                         ids=["exact", "int8"])
+def test_serve_and_merge_compiles_for_v5e(one_chip, quant, slots):
+    """One serve round of either cache tier at the graph cell's served
+    shape (B 32, b 8, so 256 pairs; k 10, or the int8 tier's m 20 with
+    its (B, m, 3) payload) compiles and fits one chip's 16 GB."""
+    spec, B, npad = GRAPH_SPEC, 32, 256
+    k = 20 if quant else 10
+    g = _shape(one_chip, (slots, spec.fetch_blocks, spec.gblk), jnp.int32)
+    if quant:
+        cache = (g, _shape(one_chip, (slots, spec.fetch_blocks, spec.vblk),
+                           jnp.int8),
+                 _shape(one_chip, (slots, spec.fetch_blocks, spec.n_qgroups),
+                        jnp.float32))
+    else:
+        cache = (g, _shape(one_chip, (slots, spec.fetch_blocks, spec.vblk),
+                           jnp.float32))
+    pairs = [_shape(one_chip, (npad,), jnp.int32)] * 4
+    compiled = DS.serve_and_merge.lower(
+        spec, cache, _shape(one_chip, (spec.n_partitions, 8), jnp.int32),
+        _shape(one_chip, (B, spec.dim), jnp.float32),
+        _shape(one_chip, (B, k), jnp.float32),
+        _shape(one_chip, (B, k, 3) if quant else (B, k), jnp.int32),
+        *pairs, _shape(one_chip, (npad,), jnp.bool_),
+        k=k, ef=48, mode="graph", n_lanes=8).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
